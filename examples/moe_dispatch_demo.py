@@ -26,7 +26,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core import collectives as cl
-from repro.parallel.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.launch.hlo_analysis import MeshLayout  # noqa: E402
 from repro.launch.hlo_module import analyze_module  # noqa: E402
 

@@ -66,7 +66,7 @@ import jax.numpy as jnp  # noqa: E402
 import functools  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 from repro.core import collectives as cl  # noqa: E402
-from repro.parallel.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 n = len(jax.devices())
 if n >= 2 and n % 2 == 0:

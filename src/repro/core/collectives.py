@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.parallel.compat import axis_size
+from jax.lax import axis_size
 
 
 # ===========================================================================

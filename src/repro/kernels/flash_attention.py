@@ -86,9 +86,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     softcap: float | None = None, scale: float | None = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """Fused attention.  q/k/v: [BH, S, D] (matched heads; GQA broadcast is
-    the caller's job).  Returns [BH, S, D] in q's dtype."""
+    the caller's job).  Returns [BH, S, D] in q's dtype.  ``interpret``
+    runs the kernel body in interpret mode (CPU validation)."""
     bh, s_len, d = q.shape
     assert k.shape == v.shape == (bh, k.shape[1], d)
     kv_len = k.shape[1]

@@ -35,44 +35,54 @@ def _mamba2_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     x = x_ref[0].astype(jnp.float32)          # [Q, dh]
-    dt = dt_ref[0].astype(jnp.float32)        # [Q]
-    a = a_ref[0, 0]                           # scalar A (negative)
+    dt = dt_ref[0, 0].astype(jnp.float32)     # [1, Q] step sizes as a row
+    a = a_ref[0]                              # [1, 1] A (negative)
     b = b_ref[0].astype(jnp.float32)          # [Q, ds]
     c = c_ref[0].astype(jnp.float32)          # [Q, ds]
-    d = d_ref[0, 0]                           # scalar skip
+    d = d_ref[0]                              # [1, 1] skip
 
-    log_a = dt * a                            # [Q] log decay per step (<=0)
-    cum = jnp.cumsum(log_a)                   # [Q] inclusive
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    lower = (ii >= jj).astype(jnp.float32)
+    log_a = dt * a                            # [1, Q] log decay per step (<=0)
+    # inclusive prefix sums as triangular matmuls, in both orientations
+    # (Mosaic has no cumsum and no vector transpose of a [1, Q] row)
+    cum_col = _nt(lower, log_a)               # [Q, 1]
+    cum_row = _nt(log_a, lower)               # [1, Q]
+    dt_col = _nt((ii == jj).astype(jnp.float32), dt)     # [Q, 1]
     # intra-chunk: M[i,j] = (C_i . B_j) * exp(cum_i - cum_j) * dt_j, j<=i
     s = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [Q, Q]
-    li = cum[:, None]
-    lj = cum[None, :]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(ii >= jj, jnp.exp(li - lj), 0.0)
-    m = s * decay * dt[None, :]
+    decay = jnp.where(ii >= jj, jnp.exp(cum_col - cum_row), 0.0)
+    m = s * decay * dt
     y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [Q, dh]
     # inter-chunk: y += exp(cum_i) * C_i^T h_prev
     h_prev = h_ref[...]                       # [ds, dh]
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum_col) * jax.lax.dot_general(
         c, h_prev, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     o_ref[0] = (y + d * x).astype(o_ref.dtype)
     # state update: h = exp(cum_Q) h_prev + sum_j exp(cum_Q - cum_j) dt_j B_j x_j^T
-    total = cum[-1]
-    w = jnp.exp(total - cum) * dt             # [Q]
+    total = jnp.sum(log_a, axis=1, keepdims=True)        # [1, 1] == cum_Q
+    w = jnp.exp(total - cum_col) * dt_col     # [Q, 1]
     h_ref[...] = jnp.exp(total) * h_prev + jax.lax.dot_general(
-        b * w[:, None], x, (((0,), (0,)), ((), ())),
+        b * w, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+
+
+def _nt(lhs, rhs):
+    """``lhs @ rhs.T`` in full f32 precision."""
+    return jax.lax.dot_general(lhs, rhs, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret"))
 def mamba2_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                 c: jax.Array, d: jax.Array, *, chunk: int = 64,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """Chunked SSD scan.
 
     Args:
@@ -83,6 +93,7 @@ def mamba2_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
       c:  [BH, S, ds] output projections.
       d:  [BH] skip coefficients.
       chunk: chunk length Q (sequence must pad to a multiple).
+      interpret: run the kernel body in interpret mode (CPU validation).
 
     Returns: y [BH, S, dh] in x.dtype.
     """
@@ -97,21 +108,24 @@ def mamba2_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
     sp = x.shape[1]
     nc = sp // chunk
+    # dt travels as one [1, Q] row per (head, chunk) and a/d as [1, 1]
+    # per head: blocks whose last two dims equal the array's, which the
+    # TPU lowering accepts for any Q (a (1, Q) block over [BH, S] is not)
     out = pl.pallas_call(
         functools.partial(_mamba2_kernel, chunk=chunk),
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dh), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, chunk), lambda h, i: (h, i)),
-            pl.BlockSpec((1, 1), lambda h, i: (h, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda h, i: (h, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda h, i: (h, 0, 0)),
             pl.BlockSpec((1, chunk, ds), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, chunk, ds), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, 1), lambda h, i: (h, 0)),
+            pl.BlockSpec((1, 1, 1), lambda h, i: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, dh), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sp, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((ds, dh), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a[:, None].astype(jnp.float32), b, c,
-      d[:, None].astype(jnp.float32))
+    )(x, dt.reshape(bh, nc, 1, chunk), a.reshape(bh, 1, 1).astype(jnp.float32),
+      b, c, d.reshape(bh, 1, 1).astype(jnp.float32))
     return out[:, :s]

@@ -40,31 +40,42 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, h_ref, *,
     k = k_ref[0].astype(jnp.float32)          # [Q, dk]
     v = v_ref[0].astype(jnp.float32)          # [Q, dv]
     logw = w_ref[0].astype(jnp.float32)       # [Q, dk] log decays (<= 0)
-    u = u_ref[0].astype(jnp.float32)          # [dk]
+    u = u_ref[0].astype(jnp.float32)          # [1, dk]
+    dk = logw.shape[1]
 
-    cum = jnp.cumsum(logw, axis=0)            # [Q, dk] inclusive log P_t
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive log P_t as a triangular matmul (Mosaic has no cumsum)
+    cum = jax.lax.dot_general(
+        (ii >= jj).astype(jnp.float32), logw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)   # [Q, dk]
     cum_prev = cum - logw                     # log P_{t-1} (P_{-1} = 1)
     r_s = r * jnp.exp(cum_prev)               # r~
     k_s = k * jnp.exp(-cum)                   # k~
     # strictly lower triangular intra-chunk attention + bonus diagonal
     att = jax.lax.dot_general(r_s, k_s, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # [Q, Q]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     att = jnp.where(ii > jj, att, 0.0)
-    bonus = jnp.sum(r * u[None, :] * k, axis=1)          # [Q]
+    bonus = jnp.sum(r * u * k, axis=1, keepdims=True)    # [Q, 1]
     y = jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    y += bonus[:, None] * v
+    y += bonus * v
     # state contribution
     h_prev = h_ref[...]                       # [dk, dv]
     y += jax.lax.dot_general(r_s, h_prev, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     o_ref[0] = y.astype(o_ref.dtype)
     # state update: S = diag(P_last) h_prev + sum_j (P_last / P_j) k_j v_j^T
-    p_last = jnp.exp(cum[-1])                 # [dk]
-    k_up = k * jnp.exp(cum[-1][None, :] - cum)           # [Q, dk]
-    h_ref[...] = p_last[:, None] * h_prev + jax.lax.dot_general(
+    total = jnp.sum(logw, axis=0, keepdims=True)         # [1, dk] log P_last
+    di = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+    dj = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
+    total_col = jax.lax.dot_general(                     # [dk, 1]
+        (di == dj).astype(jnp.float32), total, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    k_up = k * jnp.exp(total - cum)           # [Q, dk]
+    h_ref[...] = jnp.exp(total_col) * h_prev + jax.lax.dot_general(
         k_up, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
@@ -72,7 +83,7 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, h_ref, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
                u: jax.Array, *, chunk: int = 32,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """Chunked RWKV6 time-mix.
 
     Args:
@@ -80,6 +91,7 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
       logw: [BH, S, dk] log decays (<= 0; w = exp(logw)).
       u:    [BH, dk] bonus.
       chunk: chunk length Q.
+      interpret: run the kernel body in interpret mode (CPU validation).
 
     Returns: y [BH, S, dv] in v.dtype.
     """
@@ -102,11 +114,13 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
             pl.BlockSpec((1, chunk, dk), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, chunk, dv), lambda h, i: (h, i, 0)),
             pl.BlockSpec((1, chunk, dk), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((1, dk), lambda h, i: (h, 0)),
+            # u as one [1, dk] row per head: a block equal to the array's
+            # last two dims, which the TPU lowering accepts for any dk
+            pl.BlockSpec((1, 1, dk), lambda h, i: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, dv), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sp, dv), v.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u.reshape(bh, 1, dk))
     return out[:, :s]

@@ -40,12 +40,12 @@ def main():
     ap.add_argument("--variant", default="mw")
     ap.add_argument("--top", type=int, default=20)
     args = ap.parse_args()
-    from repro.launch.dryrun import VARIANTS, input_specs, SHAPES
-    from repro.launch.mesh import make_pctx
+    from repro.launch.dryrun import input_specs, SHAPES
+    from repro.launch.mesh import VARIANTS, make_pctx
     kw = dict(VARIANTS[args.variant])
     if SHAPES[args.shape].kind != "train":
         kw.setdefault("fsdp", False)
-    pctx = make_pctx(multi_pod=args.multi_pod, **kw)
+    pctx = make_pctx(512 if args.multi_pod else 256, **kw)
     kind, fn, fargs = input_specs(args.arch, args.shape, pctx)
     with pctx.mesh:
         compiled = fn.lower(*fargs).compile()

@@ -45,7 +45,7 @@ class _CollView:
     bytes_by_axis: dict
     bytes_by_kind: dict
     num_ops: int
-from repro.launch.mesh import make_pctx
+from repro.launch.mesh import VARIANTS, make_pctx
 from repro.models.api import build_model
 from repro.optim import adamw
 from repro.parallel import sharding as shd
@@ -59,28 +59,6 @@ DCN_BW = 6.25e9              # bytes/s / chip inter-pod (50 Gbps class)
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
-
-# Named sharding/schedule variants (pctx overrides).  "mw" is the
-# paper-faithful default; the rest are §Perf hillclimb levers.
-# moe_microbatch="plan" derives the pipeline chunk count G from the
-# planner's overlap-aware dispatch decision for the CELL's workload
-# (batch, fabric, modeled expert compute) instead of a hard-coded
-# preset — the knob the pipelined scoring mode genuinely tunes.
-VARIANTS = {
-    "mw": {},                                   # MultiWrite hierarchical EP
-    "auto": {"plan_policy": "auto"},            # planner-chosen schemes
-    "baseline": {"moe_scheme": "baseline"},     # unicast EP dispatch
-    "nosp": {"seq_parallel": False},            # no sequence parallelism
-    "selrem": {"remat": "selective"},           # selective remat
-    "nofsdp": {"fsdp": False},                  # pure DP (replicated params)
-    # hillclimb combos (§Perf):
-    "mwopt": {"moe_deferred_tp_reduce": True,   # deferred expert-TP psum
-              "moe_microbatch": "plan"},        # + planned pipeline chunks
-    "mwdefer": {"moe_deferred_tp_reduce": True},
-    "mwmicro": {"moe_microbatch": "plan"},
-    "baseopt": {"moe_scheme": "baseline",
-                "moe_deferred_tp_reduce": True, "moe_microbatch": "plan"},
-}
 
 # optimizer-moment dtype per variant (memory lever for the 1T cell)
 VARIANT_OPT_DTYPE = {"mwopt": jnp.bfloat16, "baseopt": jnp.bfloat16}
@@ -419,7 +397,7 @@ def _cell_pctx(arch: str, shape: ShapeSpec, multi_pod: bool, variant: str):
     planned_g = pctx_kw.get("moe_microbatch") == "plan"
     if planned_g:
         pctx_kw.pop("moe_microbatch")   # integer presets pass through
-    pctx = make_pctx(multi_pod=multi_pod, **pctx_kw)
+    pctx = make_pctx(512 if multi_pod else 256, **pctx_kw)
     if planned_g:
         pctx = dataclasses.replace(
             pctx, moe_microbatch=_planned_microbatch(arch, shape, pctx))

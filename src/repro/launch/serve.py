@@ -2,6 +2,12 @@
 
   PYTHONPATH=src python -m repro.launch.serve --arch rwkv6_7b --smoke \
       --prompts 4 --max-new 32
+
+The device count picks the layout: one device serves without a mesh,
+more take ``launch/mesh.py``'s layout for their count (four chips:
+pod 2 x data 2, experts over both), and a count with no layout is an
+error.  ``build_engine`` is the construction every caller shares
+(``chip_smoke.py`` among them).
 """
 
 from __future__ import annotations
@@ -59,7 +65,7 @@ def serve_continuous(args, cfg, engine, pctx):
     return sched.report(ttft_slo_s=ttft_slo_s, tpot_slo_s=tpot_slo_s)
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -67,12 +73,15 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--plan-policy", choices=["auto", "fixed"],
                     default="auto",
                     help="auto: MoE dispatch+combine plans per phase from "
                          "the latency-model planner (decode vs prefill "
                          "can differ, Fig 8)")
+    ap.add_argument("--moe-scheme", choices=["hierarchical", "baseline"],
+                    default="hierarchical",
+                    help="fixed policy: MoE dispatch scheme, MultiWrite "
+                         "(hierarchical) or unicast (baseline)")
     ap.add_argument("--fabric", default=None,
                     help="fabric the planner scores against: a registered "
                          "name (2x8, 4x8, 2x8r2, 2x8asym) or an inline "
@@ -116,42 +125,63 @@ def main(argv=None):
                          "link traffic would push the decode round trip "
                          "past this cap (contention-aware sweep)")
     ap.add_argument("--seed", type=int, default=0)
-    from repro.telemetry.exporter import (add_metrics_args,
-                                          finish_exporter_from_args,
-                                          start_exporter_from_args)
+    from repro.telemetry.exporter import add_metrics_args
     add_metrics_args(ap)
-    args = ap.parse_args(argv)
-    exporter = start_exporter_from_args(args)
+    return ap.parse_args(argv)
 
+
+def serving_config(args):
+    """The model config ``args`` names (``--smoke``: a reduced one)."""
     from repro.configs.base import get_config
-    from repro.models.api import build_model
-    from repro.runtime.server import ServeConfig, ServeEngine
-
     cfg = get_config(args.arch)
-    pctx = None
     if args.smoke:
         cfg = cfg.reduced(n_layers=4, d_model=128, n_heads=4, d_ff=256,
                           vocab=2048)
-    else:
-        # production mesh only when this host actually has it; otherwise
-        # keep the historical pctx-free single-host path
-        need = 512 if args.multi_pod else 256
-        if len(jax.devices()) == need:
-            import dataclasses
+    return cfg
 
-            from repro.launch.mesh import make_pctx
-            pctx = make_pctx(multi_pod=args.multi_pod, fsdp=False)
-            pctx = dataclasses.replace(pctx, plan_policy=args.plan_policy)
-        else:
-            print(f"({len(jax.devices())} device(s), production mesh "
-                  f"needs {need}: serving without a ParallelContext)")
+
+def init_params(model, pctx, seed: int, dtype):
+    """Seeded parameters in ``dtype``, created already sharded by
+    ``param_specs`` on the context's mesh: no array lands whole on one
+    device first."""
+    def init(key):
+        return jax.tree_util.tree_map(
+            lambda a: a.astype(dtype)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a,
+            model.init(key))
+
+    key = jax.random.key(seed)
+    if pctx is None:
+        return jax.jit(init)(key)
+    from repro.parallel import sharding as shd
+    specs = shd.param_specs(jax.eval_shape(init, key), model.cfg, pctx)
+    return jax.jit(init, out_shardings=shd.named(specs, pctx))(key)
+
+
+def build_engine(args, cfg, params=None, dtype=None):
+    """The serving stack ``args`` describes, on every device this process
+    sees: the parallel context (one device serves without a mesh; more
+    take the ``launch/mesh.py`` layout for their count), the bound
+    collective plan, the model, its parameters (``params``, or seeded
+    ones) and the :class:`ServeEngine` over them.  ``dtype``: the
+    activation dtype (default f32 under ``--smoke``, else bf16)."""
+    import dataclasses
+
+    from repro.models.api import build_model
+    from repro.runtime.server import ServeConfig, ServeEngine
+
+    if dtype is None:
+        dtype = jnp.float32 if args.smoke else jnp.bfloat16
+    pctx = None
+    if len(jax.devices()) > 1:
+        from repro.launch.mesh import make_pctx
+        pctx = make_pctx(fsdp=False, plan_policy=args.plan_policy,
+                         moe_scheme=args.moe_scheme)
     # resolve --fabric into the context BEFORE telemetry: probe records
     # and trace-time planner lookups must share ONE fabric fingerprint
     # (a monitor keyed to the mesh-derived topology would store records
     # the --fabric decisions never find)
     if pctx is not None and args.fabric:
-        import dataclasses
-
         from repro.core.topology import get_fabric
         pctx = dataclasses.replace(pctx, fabric=get_fabric(args.fabric))
     monitor = None
@@ -186,7 +216,7 @@ def main(argv=None):
         program = build_collective_program(
             cfg, pctx, "serve", {"prefill": (args.prompts, args.prompt_len),
                                  "decode": (args.prompts, 1)},
-            itemsize=4 if args.smoke else 2, phase_budgets=budgets)
+            itemsize=jnp.dtype(dtype).itemsize, phase_budgets=budgets)
         if program.sites:
             eplan = pctx.plan_collectives(program)
             pctx = pctx.bind(eplan)
@@ -199,13 +229,26 @@ def main(argv=None):
                 print(f"decode SLO {dec['budget_s'] * 1e6:.0f}us: "
                       f"{verdict} — contended decode "
                       f"{dec.get('contended_score_s', 0.0) * 1e6:.1f}us")
-    model = build_model(cfg, pctx, dtype=jnp.float32 if args.smoke
-                        else jnp.bfloat16)
-    params = model.init(jax.random.key(args.seed))
-    engine = ServeEngine(model, params,
-                         ServeConfig(max_new_tokens=args.max_new,
-                                     temperature=args.temperature),
-                         pctx=pctx, calibration=store, monitor=monitor)
+    model = build_model(cfg, pctx, dtype=dtype)
+    if params is None:
+        # weights are held in the dtype every matmul casts them to
+        params = init_params(model, pctx, args.seed, dtype)
+    return ServeEngine(model, params,
+                       ServeConfig(max_new_tokens=args.max_new,
+                                   temperature=args.temperature),
+                       pctx=pctx, calibration=store, monitor=monitor)
+
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.telemetry.exporter import (finish_exporter_from_args,
+                                          start_exporter_from_args)
+    args = parse_args(argv)
+    enable_compile_cache()
+    exporter = start_exporter_from_args(args)
+    cfg = serving_config(args)
+    engine = build_engine(args, cfg)
+    pctx = engine.pctx
     if args.continuous:
         rep = serve_continuous(args, cfg, engine, pctx)
         print(f"served {rep['completed']}/{args.requests} request(s) in "

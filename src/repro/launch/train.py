@@ -7,11 +7,11 @@ Local (real devices, reduced config):
 Production (multi-host TPU; this process shape is what you'd launch per
 host — jax.distributed.initialize is invoked when JAX_COORDINATOR is set):
   python -m repro.launch.train --arch kimi_k2_1t --shape train_4k \
-      --multi-pod --ckpt-dir gs://...
+      --ckpt-dir gs://...
 
-The mesh is the production (16,16) / (2,16,16) layout from launch/mesh.py;
-parallelism knobs (moe scheme, remat, SP, FSDP) come from --variant, same
-names as the dry-run.
+The mesh is launch/mesh.py's layout for the device count (256 chips:
+(16,16); 512: (2,16,16)); parallelism knobs (moe scheme, remat, SP,
+FSDP) come from --variant, same names as the dry-run.
 """
 
 from __future__ import annotations
@@ -30,7 +30,6 @@ def main(argv=None):
     ap.add_argument("--shape", default="train_4k")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config + tiny batch on local devices")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--variant", default="mw")
     ap.add_argument("--plan-policy", choices=["auto", "fixed"],
                     default=None,
@@ -75,6 +74,8 @@ def main(argv=None):
     add_metrics_args(ap)
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     exporter = start_exporter_from_args(args)
 
     if os.environ.get("JAX_COORDINATOR"):
@@ -94,10 +95,9 @@ def main(argv=None):
     else:
         import dataclasses
 
-        from repro.launch.dryrun import VARIANTS
-        from repro.launch.mesh import make_pctx
+        from repro.launch.mesh import VARIANTS, make_pctx
         variant_kw = VARIANTS[args.variant]
-        pctx = make_pctx(multi_pod=args.multi_pod, **variant_kw)
+        pctx = make_pctx(**variant_kw)
         plan_policy = args.plan_policy
         if plan_policy is None:
             # planner by default, but a variant that pins a scheme or a

@@ -5,6 +5,9 @@ functions (jit-able, shardable):
 
   init(rng)                      -> params
   loss(params, batch)            -> (scalar loss, metrics)
+  logits(params, batch)          -> full-sequence logits [B, S, V] (no
+                                    cache: the reference serving is
+                                    checked against)
   prefill(params, batch, cache)  -> (next-token logits [B, V], cache)
   decode(params, batch, cache)   -> (logits [B, V], cache)
   init_cache(batch, max_len)     -> cache pytree (zeros; dry-run uses
@@ -43,6 +46,7 @@ class Model:
     pctx: Optional[ParallelContext]
     init: Callable
     loss: Callable
+    logits: Callable
     prefill: Callable
     decode: Callable
     init_cache: Callable
@@ -115,6 +119,10 @@ def build_model(cfg: ModelConfig, pctx: Optional[ParallelContext] = None,
         total = ce + 0.01 * aux
         return total, {"ce": ce, "aux": aux}
 
+    def logits(params, batch):
+        h, _ = hidden_train(params, batch)
+        return T.logits_fn(params, cfg, h)
+
     # ---- caches -------------------------------------------------------------------
     def init_cache(batch, max_len, cache_dtype=jnp.bfloat16):
         if fam == "hybrid":
@@ -174,8 +182,8 @@ def build_model(cfg: ModelConfig, pctx: Optional[ParallelContext] = None,
         logits, cache = T.decode_step(params, cfg, pctx, x, cache)
         return logits[:, 0], cache
 
-    return Model(cfg=cfg, pctx=pctx, init=init, loss=loss, prefill=prefill,
-                 decode=decode, init_cache=init_cache)
+    return Model(cfg=cfg, pctx=pctx, init=init, loss=loss, logits=logits,
+                 prefill=prefill, decode=decode, init_cache=init_cache)
 
 
 # ---------------------------------------------------------------------------

@@ -43,7 +43,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives as cl
 from repro.models import layers as L
-from repro.parallel.compat import shard_map
+from jax import shard_map
 from repro.parallel.context import ParallelContext
 
 

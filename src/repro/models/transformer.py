@@ -190,7 +190,7 @@ def _split_tp_seq_gather(x, pctx: Optional[ParallelContext]):
     from jax.sharding import PartitionSpec as P
 
     from repro.models import layers as L
-    from repro.parallel.compat import shard_map
+    from jax import shard_map
 
     m = pctx.model_size
     nd = pctx.tp_subgroups

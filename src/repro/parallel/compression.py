@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .compat import axis_size
+from jax.lax import axis_size
 
 
 def _quantize_int8(x: jax.Array):

@@ -21,7 +21,7 @@ import warnings
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def _deprecated(name: str, repl: str) -> None:
@@ -638,7 +638,8 @@ def shard(x, pctx: Optional[ParallelContext], *spec):
     """with_sharding_constraint that no-ops without a context."""
     if pctx is None:
         return x
-    return jax.lax.with_sharding_constraint(x, P(*spec))
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(pctx.mesh, P(*spec)))
 
 
 def shard_residual(x, pctx: Optional[ParallelContext]):

@@ -329,7 +329,7 @@ class LiveProbe:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from repro.parallel.compat import shard_map
+        from jax import shard_map
 
         src = int(scenario_kw.get("src_server", 0))
         dst = int(scenario_kw.get("dst_server", 1))
@@ -369,7 +369,7 @@ class LiveProbe:
         from jax.sharding import PartitionSpec as P
 
         from repro.core import collectives as cl
-        from repro.parallel.compat import shard_map
+        from jax import shard_map
 
         plan = plan_ir.get_plan("allgather", plan_name)
         if not plan.executable:
@@ -401,7 +401,7 @@ class LiveProbe:
         from jax.sharding import PartitionSpec as P
 
         from repro.core import collectives as cl
-        from repro.parallel.compat import shard_map
+        from jax import shard_map
 
         plan = plan_ir.get_plan(op, plan_name)
         kw = plan.shard_map_kwargs()

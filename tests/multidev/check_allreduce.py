@@ -17,7 +17,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core.collectives import butterfly_psum, planned_psum  # noqa: E402
-from repro.parallel.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.parallel.compression import hierarchical_psum_flat  # noqa: E402
 
 

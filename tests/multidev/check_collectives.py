@@ -21,8 +21,9 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.parallel.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.core import collectives as cl  # noqa: E402
+from repro.launch.mesh import make_test_mesh  # noqa: E402
 
 
 def check(name, ok):
@@ -231,7 +232,7 @@ def run_moe_pipeline_checks():
     from repro.models.moe import init_moe, moe_ffn
     from repro.parallel.context import ParallelContext
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = types.SimpleNamespace(num_experts=8, top_k=2, act="silu",
                                 moe_capacity=4.0)
     d_model, f = 16, 32
@@ -278,7 +279,7 @@ def run_execution_plan_checks():
     from repro.models.moe import init_moe, moe_ffn
     from repro.parallel.context import ParallelContext
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = types.SimpleNamespace(num_experts=8, top_k=2, act="silu",
                                 moe_capacity=4.0)
     d_model, f = 16, 32
@@ -408,7 +409,7 @@ def run_split_tp_block_checks():
     cfg = get_config("mistral_nemo_12b").reduced(
         n_layers=2, d_model=64, n_heads=8, n_kv_heads=8, d_ff=128,
         vocab=256)
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_test_mesh((1, 8), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, pod_axis=None, data_axis="data",
                            model_axis="model", fsdp=False, remat="none",
                            seq_parallel=True)

@@ -14,7 +14,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.parallel.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from repro.parallel.compression import (  # noqa: E402
     compressed_psum, hierarchical_psum)
 

@@ -30,6 +30,7 @@ def test_multidevice_collectives_subprocess():
     """8-device shard_map equality suite (allgather + MoE dispatch)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"        # forced host devices, never a chip
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests/multidev/check_collectives.py")],

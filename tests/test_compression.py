@@ -12,6 +12,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_compression_collectives_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"        # forced host devices, never a chip
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable,

@@ -30,6 +30,7 @@ print("DRYRUN_SMOKE_OK", json.dumps(rl["dominant"]))
 def test_dryrun_single_cell_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"        # forced host devices, never a chip
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)

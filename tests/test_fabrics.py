@@ -402,7 +402,7 @@ class TestSplitTPAllgatherLayer:
         from jax.sharding import PartitionSpec as P
 
         from repro.models import layers as L
-        from repro.parallel.compat import shard_map
+        from jax import shard_map
         pctx = _mesh_pctx()
         x = jnp.arange(8, dtype=jnp.float32).reshape(2, 4)
 

@@ -124,7 +124,7 @@ class TestCollectiveBytes:
         """Collective inside a scan body gets the trip multiplier."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.parallel.compat import shard_map
+        from jax import shard_map
         if len(jax.devices()) < 1:
             pytest.skip("no devices")
         mesh = jax.make_mesh((1,), ("model",))

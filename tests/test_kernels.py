@@ -53,7 +53,8 @@ class TestFlashAttention:
         rng = np.random.default_rng(0)
         q, k, v = (jnp.asarray(rng.normal(size=(2, 80, 32)), jnp.float32)
                    for _ in range(3))
-        got = flash_attention(q, k, v, causal=False, block_q=32, block_k=32)
+        got = flash_attention(q, k, v, causal=False, block_q=32, block_k=32,
+                              interpret=True)
         exp = ref.attention_ref(q, k, v, causal=False)
         np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                    atol=2e-5, rtol=2e-5)
@@ -64,7 +65,7 @@ class TestFlashAttention:
         q, k, v = (jnp.asarray(rng.normal(size=(2, 128, 32)), jnp.float32)
                    for _ in range(3))
         got = flash_attention(q, k, v, causal=True, window=window,
-                              block_q=32, block_k=32)
+                              block_q=32, block_k=32, interpret=True)
         exp = ref.attention_ref(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                    atol=2e-5, rtol=2e-5)
@@ -74,7 +75,7 @@ class TestFlashAttention:
         q, k, v = (jnp.asarray(rng.normal(size=(2, 64, 32)), jnp.float32)
                    for _ in range(3))
         got = flash_attention(q, k, v, causal=True, softcap=30.0,
-                              block_q=32, block_k=32)
+                              block_q=32, block_k=32, interpret=True)
         exp = ref.attention_ref(q, k, v, causal=True, softcap=30.0)
         np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                    atol=2e-5, rtol=2e-5)
@@ -85,7 +86,8 @@ class TestFlashAttention:
         q = jnp.asarray(rng.normal(size=(2, 40, 32)), jnp.float32)
         k = jnp.asarray(rng.normal(size=(2, 72, 32)), jnp.float32)
         v = jnp.asarray(rng.normal(size=(2, 72, 32)), jnp.float32)
-        got = flash_attention(q, k, v, causal=False, block_q=32, block_k=32)
+        got = flash_attention(q, k, v, causal=False, block_q=32, block_k=32,
+                              interpret=True)
         exp = ref.attention_ref(q, k, v, causal=False)
         np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                    atol=2e-5, rtol=2e-5)
