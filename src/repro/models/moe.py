@@ -207,7 +207,11 @@ def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
 
     # The chunk pipeline is split at the dispatch/compute boundary:
     # ``dispatch_chunk`` routes + issues the dispatch collectives,
-    # ``finish_chunk`` runs the expert FFN and the combine.  The
+    # ``finish_chunk`` runs the expert FFN and the combine.  The three
+    # stages run under the named scopes ``moe.dispatch``,
+    # ``moe.expert_ffn`` and ``moe.combine``, which the compiled ops'
+    # ``op_name`` and a profiler trace carry, so that each planned
+    # site's device time can be found by name.  The
     # pipelined ``inner`` below issues the NEXT chunk's dispatch before
     # finishing the previous one, so the dispatch collectives of chunk
     # k+1 have no data dependency on the FFN/combine of chunk k and the
@@ -220,21 +224,25 @@ def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
         aux = jax.lax.pmean(aux, dp_spec)
         dispatch_fn = (cl.hierarchical_dispatch if scheme == "hierarchical"
                        else cl.baseline_dispatch)
-        exp_tok, exp_gate, st = dispatch_fn(tok, ids, gates, dcfg, epmesh)
+        with jax.named_scope("moe.dispatch"):
+            exp_tok, exp_gate, st = dispatch_fn(tok, ids, gates, dcfg,
+                                                epmesh)
         return (exp_tok, exp_gate, st), aux
 
     def finish_chunk(pack, w1, w3, w2, out_dtype):
         exp_tok, exp_gate, st = pack
-        exp_out = _expert_ffn(w1, w3, w2, exp_tok, cfg.act, expert_axis)
+        with jax.named_scope("moe.expert_ffn"):
+            exp_out = _expert_ffn(w1, w3, w2, exp_tok, cfg.act, expert_axis)
         if scheme == "hierarchical":
             combine_fn = (cl.hierarchical_combine
                           if combine_scheme == "hierarchical"
                           else cl.hierarchical_combine_unicast)
         else:
             combine_fn = cl.baseline_combine
-        out = combine_fn(exp_out, exp_gate, st)
-        if pctx.moe_deferred_tp_reduce:
-            out = jax.lax.psum(out, pctx.model_axis)
+        with jax.named_scope("moe.combine"):
+            out = combine_fn(exp_out, exp_gate, st)
+            if pctx.moe_deferred_tp_reduce:
+                out = jax.lax.psum(out, pctx.model_axis)
         return out.astype(out_dtype)
 
     def one_chunk(tok, router, w1, w3, w2):

@@ -10,6 +10,7 @@ friendly — finished slots are masked, not removed).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Callable, Optional
 
@@ -17,10 +18,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry import spans
+
 
 def _metrics():
-    """Lazy: the metrics plane lives in repro.telemetry, which must not
-    be a hard import of the runtime layer."""
+    """The process-wide metrics registry, looked up where an event
+    happens."""
     from repro.telemetry import metrics as _m
     return _m.default_registry()
 
@@ -54,6 +57,7 @@ class CohortState:
     logits: object
     key: object
     batch: int
+    cohort: int = 0               # id, in the order the engine started it
 
 
 class ServeEngine:
@@ -93,6 +97,7 @@ class ServeEngine:
         initial = pctx.execution_plan if pctx is not None else None
         self._binder = PlanBinder(self._trace_plan, plan=initial)
         self.stats = {"prefill_s": 0.0, "decode_s": 0.0, "tokens": 0}
+        self._cohort_ids = itertools.count()
         self._stale_warned = False
         # (batch, prompt_len)-keyed memos: per-step scheduler queries
         # (plan_report, admission probes) must never re-derive the
@@ -334,37 +339,54 @@ class ServeEngine:
         b, s = prompts.shape
         max_new = max_new or self.cfg.max_new_tokens
         model = self._binder.artifact.model
+        cohort = next(self._cohort_ids)
         t0 = time.monotonic()
-        cache = model.init_cache(b, s + max_new, self.cfg.cache_dtype)
-        from repro.data.pipeline import batch_for_model
-        batch = batch_for_model(
-            cfg, {"tokens": prompts, "labels": prompts})
-        batch.pop("labels", None)
-        logits, cache = self._prefill(self.params, batch, cache)
-        state = CohortState(cache=cache, logits=logits,
-                            key=jax.random.key(seed), batch=b)
-        tokens = self._sample(state)
+        with spans.span("engine.prefill", batch=b, prompt_len=s,
+                        cohort=cohort):
+            with spans.span("engine.cache_init"):
+                cache = model.init_cache(b, s + max_new,
+                                         self.cfg.cache_dtype)
+            with spans.span("engine.embed"):
+                from repro.data.pipeline import batch_for_model
+                batch = batch_for_model(
+                    cfg, {"tokens": prompts, "labels": prompts})
+                batch.pop("labels", None)
+            with spans.span("engine.launch"):
+                logits, cache = self._prefill(self.params, batch, cache)
+                state = CohortState(cache=cache, logits=logits,
+                                    key=jax.random.key(seed), batch=b,
+                                    cohort=cohort)
+                sampled = self._sample(state)
+            with spans.span("engine.wait"):
+                tokens = np.asarray(sampled, np.int32)
         return state, tokens, time.monotonic() - t0
 
     def step_cohort(self, state: "CohortState", tokens: np.ndarray):
         """One decode round: consume the cohort's last sampled tokens,
         sample the next.  Returns ``(state, tokens, wall_s)``."""
         t0 = time.monotonic()
-        dec_in = self._decode_batch(np.asarray(tokens, np.int32)[:, None])
-        state.logits, state.cache = self._decode(
-            self.params, dec_in, state.cache)
-        tokens = self._sample(state)
+        with spans.span("engine.decode", batch=state.batch,
+                        cohort=state.cohort):
+            with spans.span("engine.embed"):
+                dec_in = self._decode_batch(
+                    np.asarray(tokens, np.int32)[:, None])
+            with spans.span("engine.launch"):
+                state.logits, state.cache = self._decode(
+                    self.params, dec_in, state.cache)
+                sampled = self._sample(state)
+            with spans.span("engine.wait"):
+                tokens = np.asarray(sampled, np.int32)
         return state, tokens, time.monotonic() - t0
 
-    def _sample(self, state: "CohortState") -> np.ndarray:
+    def _sample(self, state: "CohortState"):
+        """Dispatch the sampling of the next tokens; the device array it
+        returns is read on the host by the caller."""
         if self.cfg.temperature > 0:
             state.key, sub = jax.random.split(state.key)
-            nxt = jax.random.categorical(
+            return jax.random.categorical(
                 sub, jnp.asarray(state.logits) / self.cfg.temperature,
                 axis=-1)
-        else:
-            nxt = jnp.argmax(state.logits, axis=-1)
-        return np.asarray(nxt, np.int32)
+        return jnp.argmax(state.logits, axis=-1)
 
     def generate(self, prompts: np.ndarray, max_new: Optional[int] = None,
                  seed: int = 0) -> np.ndarray:

@@ -3,7 +3,10 @@
 A :class:`Request` carries its whole serving lifecycle in virtual time
 (seconds on the scheduler's clock, never the wall): arrival, admission
 (queue exit), first token (TTFT) and finish — the quantities the
-per-request SLO classes and the serving histograms cut.
+per-request SLO classes and the serving histograms cut.  The wall time
+a request waits, from ``push`` to the ``pop_ready`` that takes it, is
+its ``request.queued`` span (:mod:`repro.telemetry.spans`, while the
+recorder is on).
 
 :class:`RequestQueue` is an arrival-time-gated priority FIFO: only
 requests whose ``arrival_s`` has passed are visible, and within the
@@ -19,6 +22,8 @@ from __future__ import annotations
 import dataclasses
 import itertools
 from typing import List, Optional
+
+from repro.telemetry import spans
 
 DEADLINE_CLASSES = ("interactive", "standard", "batch")
 
@@ -101,6 +106,8 @@ class RequestQueue:
         self._order[id(req)] = next(self._seq)
         self._pending.append(req)
         self.pushed += 1
+        # wall time until the pop_ready that takes it, whatever arrival_s
+        spans.begin("request.queued", id(req), rid=req.rid)
 
     def __len__(self) -> int:
         return len(self._pending)
@@ -137,5 +144,6 @@ class RequestQueue:
         self._pending = [r for r in self._pending if id(r) not in taken]
         for r in take:
             self._order.pop(id(r), None)
+            spans.end("request.queued", id(r))
         self.popped += len(take)
         return take

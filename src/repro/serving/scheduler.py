@@ -26,6 +26,10 @@ back to measured wall when an engine runs without a probe), so the
 whole tier is deterministic and CPU-simulation-testable.  With
 ``engine=None`` no tokens are computed at all — pure scheduling
 simulation, what ``bench_serving`` sweeps and the stress soak drives.
+While the span recorder (:mod:`repro.telemetry.spans`) is on, each
+:meth:`step` is also timed on the wall clock: ``sched.iteration`` around
+it, ``sched.admit``, ``sched.decode_round`` and ``sched.finalize``
+inside it.
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ import numpy as np
 from repro.core.plan import batch_bucket
 from repro.serving.admission import AdmissionController
 from repro.serving.queue import CLASS_TTFT_SLACK, Request, RequestQueue
+from repro.telemetry import spans
 
 
 def _metrics():
@@ -139,6 +144,10 @@ class BatchScheduler:
     def step(self) -> bool:
         """One scheduling iteration; False when fully idle (queue empty
         and nothing in flight)."""
+        with spans.span("sched.iteration"):
+            return self._iterate()
+
+    def _iterate(self) -> bool:
         self.iterations += 1
         # (1) step boundary: staged bucket/failover plans land here
         if self.binder is not None and self.binder.swap_if_pending():
@@ -150,14 +159,17 @@ class BatchScheduler:
         ready = self.queue.ready_count(self.now)
         barrier = self.static_batching and bool(self.cohorts)
         if ready and not barrier:
-            dec = self.admission.decide(
-                in_flight=self.in_flight, ready=ready,
-                oldest_wait_s=self.queue.oldest_wait_s(self.now),
-                bound_bucket=self.bound_bucket)
-            if dec.stage_bucket is not None:
-                self._stage_bucket(dec.stage_bucket)
-            if dec.admit > 0:
-                joiners = self.queue.pop_ready(self.now, dec.admit)
+            with spans.span("sched.admit") as sp:
+                dec = self.admission.decide(
+                    in_flight=self.in_flight, ready=ready,
+                    oldest_wait_s=self.queue.oldest_wait_s(self.now),
+                    bound_bucket=self.bound_bucket)
+                if dec.stage_bucket is not None:
+                    self._stage_bucket(dec.stage_bucket)
+                if dec.admit > 0:
+                    joiners = self.queue.pop_ready(self.now, dec.admit)
+                sp.set(admitted=len(joiners),
+                       rids=[r.rid for r in joiners])
         if not joiners and not self.cohorts:
             nxt = self.queue.next_arrival_s(self.now)
             if nxt is None:
@@ -173,7 +185,8 @@ class BatchScheduler:
         if old_cohorts:
             dt += self._decode_round(old_cohorts)
         self.now += dt
-        self._finalize()
+        with spans.span("sched.finalize"):
+            self._finalize()
         reg = _metrics()
         reg["repro_serving_queue_depth"].set(self.queue.ready_count(self.now))
         reg["repro_serving_in_flight"].set(self.in_flight)
@@ -219,18 +232,25 @@ class BatchScheduler:
     def _decode_round(self, cohorts: List[_Cohort]) -> float:
         dt = 0.0
         total = sum(c.live for c in cohorts)   # payload BEFORE finishes
-        for cohort in cohorts:
-            if self.engine is not None:
-                state, toks, wall = self.engine.step_cohort(
-                    cohort.state, cohort.pending)
-                cohort.state = state
-                cohort.pending = toks
-                self.wall["decode_s"] += wall
-                if self.probe is None:
-                    dt += wall
-                self._emit(cohort, toks)
-            else:
-                self._emit(cohort, None)
+        launches = rows = 0
+        with spans.span("sched.decode_round") as sp:
+            for cohort in cohorts:
+                if self.engine is not None:
+                    state, toks, wall = self.engine.step_cohort(
+                        cohort.state, cohort.pending)
+                    cohort.state = state
+                    cohort.pending = toks
+                    self.wall["decode_s"] += wall
+                    if self.probe is None:
+                        dt += wall
+                    launches += 1
+                    rows += len(cohort.requests)
+                    self._emit(cohort, toks)
+                else:
+                    self._emit(cohort, None)
+            # a launch computes every row of its cohort, finished or not;
+            # ``live`` rows are those whose request still wanted a token
+            sp.set(launches=launches, rows=rows, live=total)
         if self.probe is not None:
             if total > 0:
                 dt = self.probe.decode_step_s(

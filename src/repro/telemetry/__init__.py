@@ -15,6 +15,8 @@ planner's feedback loop.
     exporter.py stdlib /metrics HTTP endpoint + snapshot-to-file
     slo.py      good/acceptable/poor banding of measured latency
                 against the planner's own prediction
+    spans.py    wall-clock spans of the serving path, in memory and on
+                the profiler's clock; off unless spans.enable() is called
 
 Consumed by: ParallelContext(calibration=...), train.py/serve.py
 --calibrate, dryrun --calibration, ServeEngine.plan_report,
