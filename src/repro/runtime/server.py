@@ -41,11 +41,14 @@ class _ServeLowering:
     """Traced-lowering artifact of one ExecutionPlan: the jitted
     prefill/decode pair whose MoE layers consumed exactly that plan's
     decisions at trace time, plus the bound context they read them
-    from."""
+    from, and the jitted cache builder with the cache shapes it has
+    compiled (``(batch, max_len, dtype)``)."""
     pctx: object
     model: object
     prefill: Callable
     decode: Callable
+    init_cache: Callable
+    cache_shapes: set = dataclasses.field(default_factory=set)
 
 
 @dataclasses.dataclass
@@ -126,9 +129,12 @@ class ServeEngine:
             else:
                 from repro.models.api import build_model
                 model = build_model(self.model.cfg, pctx)
+        # the cache is built by one compiled program per cohort shape:
+        # eager, every buffer of it is a dispatch of its own
         return _ServeLowering(
             pctx=pctx, model=model, prefill=jax.jit(model.prefill),
-            decode=jax.jit(model.decode, donate_argnums=(2,)))
+            decode=jax.jit(model.decode, donate_argnums=(2,)),
+            init_cache=jax.jit(model.init_cache, static_argnums=(0, 1, 2)))
 
     def rebind(self, plan) -> bool:
         """Stage ``plan`` (e.g. a failover replan from the drift
@@ -338,14 +344,17 @@ class ServeEngine:
         cfg = self.model.cfg
         b, s = prompts.shape
         max_new = max_new or self.cfg.max_new_tokens
-        model = self._binder.artifact.model
+        lowering = self._binder.artifact
         cohort = next(self._cohort_ids)
         t0 = time.monotonic()
         with spans.span("engine.prefill", batch=b, prompt_len=s,
                         cohort=cohort):
-            with spans.span("engine.cache_init"):
-                cache = model.init_cache(b, s + max_new,
-                                         self.cfg.cache_dtype)
+            shape = (b, int(s + max_new), self.cfg.cache_dtype)
+            # first: this lowering's builder compiles for the shape now
+            with spans.span("engine.cache_init",
+                            first=shape not in lowering.cache_shapes):
+                cache = lowering.init_cache(*shape)
+                lowering.cache_shapes.add(shape)
             with spans.span("engine.embed"):
                 from repro.data.pipeline import batch_for_model
                 batch = batch_for_model(

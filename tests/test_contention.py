@@ -456,6 +456,7 @@ class TestStaleness:
         class _Stub:
             prefill = staticmethod(lambda *a: None)
             decode = staticmethod(lambda *a: None)
+            init_cache = staticmethod(lambda *a: None)
 
         engine = ServeEngine(_Stub(), None, pctx=pctx)
         dp = default_planner()

@@ -1,7 +1,7 @@
 """Serving tier: queue, traffic, planner-informed admission, the
 iteration-level scheduler (join/exit between decode steps), bit-exact
-continuous-vs-one-shot generation, engine plan memoization, and the
-per-request SLO bands."""
+continuous-vs-one-shot generation, the cohort cache built by one
+compiled call, engine plan memoization, and the per-request SLO bands."""
 
 import jax
 import jax.numpy as jnp
@@ -328,6 +328,121 @@ class TestEngineCohorts:
 
 
 # ---------------------------------------------------------------------------
+# the cohort's cache: one compiled call, the same cache as the eager builder
+# ---------------------------------------------------------------------------
+
+def _eager_cache(engine):
+    """``engine`` building each cohort's cache with ``model.init_cache``
+    run eagerly (one dispatch per buffer), as the engine once did."""
+    lowering = engine.plan_binder.artifact
+    lowering.init_cache = lowering.model.init_cache
+    return engine
+
+
+def _handed_caches(engine):
+    """The caches ``start_cohort`` hands to the prefill, in order."""
+    lowering = engine.plan_binder.artifact
+    prefill, handed = lowering.prefill, []
+
+    def recording(params, batch, cache):
+        handed.append(cache)
+        return prefill(params, batch, cache)
+
+    lowering.prefill = recording
+    return handed
+
+
+def _same_cache(built, eager):
+    assert (jax.tree_util.tree_structure(built)
+            == jax.tree_util.tree_structure(eager))
+    for a, e in zip(jax.tree_util.tree_leaves(built),
+                    jax.tree_util.tree_leaves(eager)):
+        assert (a.shape, a.dtype, a.sharding) == (e.shape, e.dtype,
+                                                  e.sharding)
+        assert not np.asarray(a, np.float32).any()
+
+
+class TestCohortCacheBuilder:
+    @pytest.mark.parametrize("arch", ["qwen2_vl_2b", "mistral_nemo_12b",
+                                      "dbrx_132b", "zamba2_7b",
+                                      "rwkv6_7b"])
+    def test_jitted_cache_and_tokens_match_the_eager_builder(self, arch):
+        from repro.configs.base import get_config
+        from repro.models.api import build_model
+        from repro.runtime.server import ServeConfig, ServeEngine
+        cfg = get_config(arch).reduced(n_layers=2, d_model=32, n_heads=2,
+                                       d_ff=64, vocab=64)
+        model = build_model(cfg, dtype=jnp.float32)
+        params = model.init(jax.random.key(0))
+        prompts = np.random.default_rng(6).integers(
+            0, 64, size=(2, 8)).astype(np.int32)
+        engine = ServeEngine(model, params, ServeConfig(max_new_tokens=4))
+        handed = _handed_caches(engine)
+        out = engine.generate(prompts)
+        (built,) = handed
+        _same_cache(built, model.init_cache(2, 12, engine.cfg.cache_dtype))
+        eager = _eager_cache(ServeEngine(model, params,
+                                         ServeConfig(max_new_tokens=4)))
+        np.testing.assert_array_equal(out, eager.generate(prompts))
+
+
+EP_CACHE = """
+import json
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs.base import get_config
+from repro.launch import serve
+
+cfg = get_config("dbrx_132b").reduced(n_layers=2, d_model=32, n_heads=2,
+                                      d_ff=32, num_experts=4, vocab=64)
+args = serve.parse_args(["--arch", "dbrx_132b", "--smoke", "--prompts", "4",
+                         "--prompt-len", "8", "--max-new", "3",
+                         "--plan-policy", "fixed"])
+prompts = np.random.default_rng(7).integers(0, 64, size=(4, 8)).astype(
+    np.int32)
+out = {}
+for path in ("jitted", "eager"):
+    engine = serve.build_engine(args, cfg)
+    lowering = engine.plan_binder.artifact
+    if path == "eager":
+        lowering.init_cache = lowering.model.init_cache
+    prefill, handed = lowering.prefill, []
+    lowering.prefill = lambda p, b, c: handed.append(c) or prefill(p, b, c)
+    tokens = engine.generate(prompts)
+    leaves = jax.tree_util.tree_leaves(handed[0])
+    out[path] = {
+        "mesh": dict(engine.pctx.mesh.shape),
+        "tokens": tokens.tolist(),
+        "leaves": [[list(a.shape), str(a.dtype), repr(a.sharding),
+                    bool(np.asarray(a, np.float32).any())] for a in leaves]}
+print(json.dumps(out))
+"""
+
+
+def test_jitted_cache_on_the_expert_parallel_mesh_matches_eager():
+    """Four forced host devices (in a subprocess, so the count never
+    reaches this process): a cohort served on the expert-parallel mesh
+    with the jitted builder gets the eager builder's tokens, and its
+    cache leaves the same shapes, dtypes, placement and zeros."""
+    import json
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    proc = subprocess.run([sys.executable, "-c", EP_CACHE], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    jitted, eager = out["jitted"], out["eager"]
+    assert jitted["mesh"] == {"pod": 2, "data": 2, "model": 1}
+    assert jitted["tokens"] == eager["tokens"]
+    assert jitted["leaves"] == eager["leaves"]
+    assert not any(leaf[3] for leaf in jitted["leaves"])
+
+
+# ---------------------------------------------------------------------------
 # engine plan memoization (per-step queries must not re-plan)
 # ---------------------------------------------------------------------------
 
@@ -350,6 +465,7 @@ class TestEnginePlanMemo:
                 self.cfg = c
             prefill = staticmethod(lambda *a: None)
             decode = staticmethod(lambda *a: None)
+            init_cache = staticmethod(lambda *a: None)
 
         return ServeEngine(_Stub(cfg), None, pctx=pctx)
 

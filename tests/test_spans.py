@@ -2,7 +2,7 @@
 
 The recorder on its own (off, on, nesting, attributes, the bound, self
 time); a tiny CPU ``BatchScheduler`` over a ``ServeEngine`` with the
-recorder on; the MoE layer's named scopes on the compiled collectives
+recorder on, and the ``first`` mark of its cache builds; the MoE layer's named scopes on the compiled collectives
 of four forced host devices (in a subprocess, so that the device count
 never reaches this process); and the chip benchmark's trace reduction
 putting an idle gap down to the innermost program span.
@@ -233,6 +233,23 @@ def test_scheduler_over_engine_records_each_launch(engine):
     for name in ("sched.admit", "sched.finalize"):
         assert {rows[rows[i].parent].name for i in by[name]} == {
             "sched.iteration"}
+
+
+def test_cache_init_marks_the_first_build_of_each_shape(engine):
+    """``first`` is true where the engine's cache builder compiles: the
+    first ``start_cohort`` of a (batch, max_len, dtype), and no other."""
+    builder = engine.plan_binder.artifact.init_cache
+    prompts = np.zeros((3, 8), np.int32)       # a batch no other test uses
+    spans.enable()
+    engine.start_cohort(prompts, max_new=4)
+    compiled = builder._cache_size()
+    engine.start_cohort(prompts, max_new=4)
+    assert builder._cache_size() == compiled  # nothing compiled again
+    engine.start_cohort(prompts, max_new=5)
+    rows = spans.drain()
+    assert [r.attrs for r in rows if r.name == "engine.cache_init"] == [
+        {"first": True}, {"first": False}, {"first": True}]
+    assert builder._cache_size() == compiled + 1
 
 
 # -- the MoE layer's named scopes ---------------------------------------------
