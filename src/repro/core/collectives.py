@@ -361,10 +361,12 @@ def _dest_coords(expert_ids: jax.Array, per_rank: int, ep_per_pod: int):
 
 def hierarchical_dispatch(tokens: jax.Array, expert_ids: jax.Array,
                           gates: jax.Array, cfg: DispatchConfig,
-                          mesh: EPMesh):
+                          mesh: EPMesh, valid: jax.Array | None = None):
     """MultiWrite MoE dispatch (paper §3.2 / §4).
 
-    Per chip inputs: tokens [N, H]; expert_ids [N, K] i32; gates [N, K] f32.
+    Per chip inputs: tokens [N, H]; expert_ids [N, K] i32; gates [N, K] f32;
+    valid [N] bool or None (every row): rows that are False (padding) take
+    no slot at any stage.
 
     Stage 1 — ONE copy per (token, destination pod) crosses the pod axis,
     landing on the same-index chip (the rail relay).  The ep-rank bitmap
@@ -396,7 +398,8 @@ def hierarchical_dispatch(tokens: jax.Array, expert_ids: jax.Array,
         ep_any.astype(jnp.int32) << jnp.arange(d), axis=-1).astype(jnp.int32)
 
     cp = max(1, int(round(n * cfg.pod_capacity)))
-    valid = jnp.ones((n,), bool)
+    if valid is None:
+        valid = jnp.ones((n,), bool)
     send_tok, map_pod = pack_by_bitmap(tokens, pod_bits, valid, p, cp)
     # metadata rides along (the §4.1 in-packet metadata): ep bitmap for the
     # DESTINATION pod + source row id + (ids, gates) for expert/combine use.
@@ -612,10 +615,12 @@ def hierarchical_combine_unicast(expert_out: jax.Array, exp_gate: jax.Array,
 # ===========================================================================
 
 def baseline_dispatch(tokens: jax.Array, expert_ids: jax.Array,
-                      gates: jax.Array, cfg: DispatchConfig, mesh: EPMesh):
+                      gates: jax.Array, cfg: DispatchConfig, mesh: EPMesh,
+                      valid: jax.Array | None = None):
     """Unicast dispatch: pack one copy per (token, destination RANK) and
     all_to_all over the flattened (pod, ep) domain — k_remote redundant
-    copies of each token cross the pod axis (the paper's baseline)."""
+    copies of each token cross the pod axis (the paper's baseline).
+    ``valid`` as in :func:`hierarchical_dispatch`."""
     n, h = tokens.shape
     k = expert_ids.shape[-1]
     per_rank = expert_placement(cfg, mesh)
@@ -627,11 +632,13 @@ def baseline_dispatch(tokens: jax.Array, expert_ids: jax.Array,
                            << jnp.arange(min(31, r - w * 31)), axis=-1)
                    for w in range((r + 30) // 31)]
     cr = max(1, int(round(n * cfg.pod_capacity)))
+    if valid is None:
+        valid = jnp.ones((n,), bool)
     # pack per rank using multi-word bitmaps
     outs, maps = [], []
     for w, bits in enumerate(rank_bits32):
         nd = min(31, r - w * 31)
-        o, m = pack_by_bitmap(tokens, bits, jnp.ones((n,), bool), nd, cr)
+        o, m = pack_by_bitmap(tokens, bits, valid, nd, cr)
         outs.append(o)
         maps.append(m)
     send_tok = jnp.concatenate(outs, axis=0)                      # [R, Cr, H]

@@ -140,19 +140,26 @@ def input_specs(arch: str, shape_name: str, pctx, *, opt_dtype=None):
         lambda: model.init_cache(shape.global_batch, cache_len))
     cspecs = shd.cache_specs(cache_sds, cfg, pctx)
     cache_in = shd.with_sharding(cache_sds, cspecs, pctx)
-    cache_out = jax.tree_util.tree_map(lambda s: s.sharding, cache_in)
-    b = shape.global_batch
+    out = serve_out_shardings(cfg, pctx, shape.global_batch, cache_in)
+    step = model.prefill if shape.kind == "prefill" else model.decode
+    fn = jax.jit(step, donate_argnums=(2,), out_shardings=out)
+    return shape.kind, fn, (params_in, batch_in, cache_in)
+
+
+def serve_out_shardings(cfg, pctx, batch: int, cache_in):
+    """``out_shardings`` of a prefill or decode step, whose outputs are
+    ``(logits, cache, stats)`` (``models/api.py``) for every family:
+    logits split like the batch and the vocabulary where they divide,
+    the cache where it came in, and the step's counters (none for a
+    dense stack) replicated."""
     logits_spec = jax.sharding.PartitionSpec(
-        pctx.dp_axes if b % (pctx.num_pods * pctx.data_size) == 0 else None,
+        pctx.dp_axes if batch % (pctx.num_pods * pctx.data_size) == 0
+        else None,
         pctx.model_axis if cfg.vocab % pctx.model_size == 0 else None)
-    logits_out = jax.sharding.NamedSharding(pctx.mesh, logits_spec)
-    if shape.kind == "prefill":
-        fn = jax.jit(model.prefill, donate_argnums=(2,),
-                     out_shardings=(logits_out, cache_out))
-        return "prefill", fn, (params_in, batch_in, cache_in)
-    fn = jax.jit(model.decode, donate_argnums=(2,),
-                 out_shardings=(logits_out, cache_out))
-    return "decode", fn, (params_in, batch_in, cache_in)
+    cache_out = jax.tree_util.tree_map(lambda s: s.sharding, cache_in)
+    return (jax.sharding.NamedSharding(pctx.mesh, logits_spec), cache_out,
+            jax.sharding.NamedSharding(pctx.mesh,
+                                       jax.sharding.PartitionSpec()))
 
 
 # ---------------------------------------------------------------------------

@@ -8,10 +8,21 @@ functions (jit-able, shardable):
   logits(params, batch)          -> full-sequence logits [B, S, V] (no
                                     cache: the reference serving is
                                     checked against)
-  prefill(params, batch, cache)  -> (next-token logits [B, V], cache)
-  decode(params, batch, cache)   -> (logits [B, V], cache)
+  prefill(params, batch, cache)  -> (next-token logits [B, V], cache,
+                                     stats)
+  decode(params, batch, cache)   -> (logits [B, V], cache, stats)
+                                    (stats: a dict of device counters,
+                                    {} for a stack without MoE layers,
+                                    so its program has no extra output;
+                                    MoE stacks give "moe_dropped",
+                                    per-rank counts [R] int32 of the
+                                    (token, expert) pairs the dispatch
+                                    dropped, summed over layers)
   init_cache(batch, max_len)     -> cache pytree (zeros; dry-run uses
                                     eval_shape on this)
+  moe_plan(n_tokens)             -> the MoEPlan each MoE layer runs for
+                                    a call of n_tokens token rows (None
+                                    for a model without MoE layers)
 
 Batch formats (kind -> keys):
   tokens     {"tokens" [B,S] i32, "labels" [B,S] i32}
@@ -32,6 +43,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
+from repro.models import moe as M
 from repro.models import rwkv, ssm, transformer as T
 from repro.parallel.context import ParallelContext
 
@@ -50,6 +62,7 @@ class Model:
     prefill: Callable
     decode: Callable
     init_cache: Callable
+    moe_plan: Callable = lambda n_tokens: None
 
 
 def _positions_for(cfg, b, s):
@@ -143,6 +156,9 @@ def build_model(cfg: ModelConfig, pctx: Optional[ParallelContext] = None,
         return T.init_cache(cfg, batch, max_len, cache_dtype)
 
     # ---- prefill ---------------------------------------------------------------------
+    def next_logits(params, h):
+        return T.logits_fn(params, cfg, h, last_only=True)[:, 0]
+
     def prefill(params, batch, cache):
         if fam == "encdec":
             tgt = L.embed(params["embed"], batch["tgt_tokens"], dtype)
@@ -151,16 +167,16 @@ def build_model(cfg: ModelConfig, pctx: Optional[ParallelContext] = None,
             logits, cache = T.prefill_encdec(
                 params, cfg, pctx, batch["src_embeds"].astype(dtype), tgt,
                 pos, cache)
-            return logits[:, 0], cache
+            return logits[:, 0], cache, {}
         x, pos = embed_in(params, batch)
         if fam == "hybrid":
             h, cache = ssm.zamba2_prefill(params, cfg, pctx, x, cache)
-            return T.logits_fn(params, cfg, h, last_only=True)[:, 0], cache
+            return next_logits(params, h), cache, {}
         if fam == "rwkv":
             h, cache = rwkv.rwkv6_prefill(params, cfg, pctx, x, cache)
-            return T.logits_fn(params, cfg, h, last_only=True)[:, 0], cache
-        logits, cache = T.prefill(params, cfg, pctx, x, pos, cache)
-        return logits[:, 0], cache
+            return next_logits(params, h), cache, {}
+        logits, cache, stats = T.prefill(params, cfg, pctx, x, pos, cache)
+        return logits[:, 0], cache, stats
 
     # ---- decode ----------------------------------------------------------------------
     def decode(params, batch, cache):
@@ -172,18 +188,25 @@ def build_model(cfg: ModelConfig, pctx: Optional[ParallelContext] = None,
                 x = x * jnp.asarray(cfg.d_model ** 0.5, dtype)
         if fam == "encdec":
             logits, cache = T.decode_step_encdec(params, cfg, pctx, x, cache)
-            return logits[:, 0], cache
+            return logits[:, 0], cache, {}
         if fam == "hybrid":
             h, cache = ssm.zamba2_decode_step(params, cfg, pctx, x, cache)
-            return T.logits_fn(params, cfg, h, last_only=True)[:, 0], cache
+            return next_logits(params, h), cache, {}
         if fam == "rwkv":
             h, cache = rwkv.rwkv6_decode_step(params, cfg, pctx, x, cache)
-            return T.logits_fn(params, cfg, h, last_only=True)[:, 0], cache
-        logits, cache = T.decode_step(params, cfg, pctx, x, cache)
-        return logits[:, 0], cache
+            return next_logits(params, h), cache, {}
+        logits, cache, stats = T.decode_step(params, cfg, pctx, x, cache)
+        return logits[:, 0], cache, stats
+
+    def moe_plan(n_tokens):
+        if not cfg.is_moe:
+            return None
+        return M.plan_moe(cfg, pctx, n_tokens, cfg.d_model, cfg.expert_d_ff,
+                          jnp.dtype(dtype).itemsize)
 
     return Model(cfg=cfg, pctx=pctx, init=init, loss=loss, logits=logits,
-                 prefill=prefill, decode=decode, init_cache=init_cache)
+                 prefill=prefill, decode=decode, init_cache=init_cache,
+                 moe_plan=moe_plan)
 
 
 # ---------------------------------------------------------------------------

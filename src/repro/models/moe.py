@@ -133,22 +133,91 @@ def load_balance_loss(logits, ids, num_experts: int):
     return num_experts * jnp.sum(f * p_mean)
 
 
+@dataclasses.dataclass(frozen=True)
+class MoEPlan:
+    """What one MoE layer runs for a token count, decided on static
+    shapes: the dispatch and return-path schemes, the pipeline chunk
+    count G, and the rows padded on so that the data-parallel ranks
+    split the tokens evenly (0 where they divide)."""
+    dispatch: str            # "hierarchical" (MultiWrite) or "baseline"
+    combine: str             # "hierarchical" (relay-reduced) or "baseline"
+    g: int
+    pad_rows: int
+    n_local: int             # token rows per rank, padding included
+
+    def attrs(self) -> dict:
+        """The plan as span attributes (``engine.prefill`` /
+        ``engine.decode``)."""
+        return {"moe_dispatch": self.dispatch, "moe_combine": self.combine,
+                "moe_g": self.g, "ep_pad_rows": self.pad_rows}
+
+
+def plan_moe(cfg, pctx: ParallelContext | None, n_tokens: int, d: int,
+             f: int, itemsize: int) -> MoEPlan:
+    """The plan :func:`moe_ffn` runs for ``n_tokens`` rows of width ``d``
+    (activation ``itemsize`` bytes) through experts of hidden width
+    ``f``.
+
+    The WHOLE round trip — dispatch scheme, return-path scheme and the
+    shared pipeline chunk count G — is one jointly-planned decision: a
+    bound ExecutionPlan resolves it by declared-site lookup; under
+    plan_policy="auto" without a bound plan the same joint sweep runs ad
+    hoc (§5.2 dynamic workflow — decode traces pick the unicast pair at
+    small batch, prefill/train cross to MultiWrite, and the
+    shared-pipeline scorer picks the G where overlapping
+    dispatch/compute/combine chunks beats BOTH halves' per-chunk launch
+    alphas); the declared moe_scheme/moe_combine/moe_microbatch knobs
+    apply under "fixed"."""
+    if pctx is None:
+        return MoEPlan("hierarchical", "hierarchical", 1, 0, n_tokens)
+    ranks = pctx.num_pods * pctx.data_size
+    pad = -n_tokens % ranks
+    n_local = (n_tokens + pad) // ranks
+    from repro.core.latency_model import moe_overlap_compute_s
+    compute_s = moe_overlap_compute_s(n_local, cfg.top_k, d, f,
+                                      tp=pctx.model_size)
+    kw = dict(tokens_per_rank=n_local, token_bytes=d * itemsize,
+              compute_s=compute_s)
+    pipe_kw = pctx.moe_pipeline_kwargs(cfg.num_experts, cfg.top_k, **kw)
+    # the chosen G must divide the local token count; gcd clamps it to
+    # the largest divisor <= G (pow-2 grids always divide pow-2 batches).
+    # A clamp re-resolves the configuration AT the executed G: the
+    # scheme pair is taken from the joint sweep's candidates at the
+    # depth the pipeline actually runs, not one it never honors.
+    microbatch = math.gcd(max(1, int(pipe_kw["microbatch"])), n_local) or 1
+    if microbatch != int(pipe_kw["microbatch"]):
+        pipe_kw = pctx.moe_pipeline_kwargs(cfg.num_experts, cfg.top_k,
+                                           microbatch=microbatch, **kw)
+    return MoEPlan(pipe_kw["moe_scheme"], pipe_kw["moe_combine"],
+                   microbatch, pad, n_local)
+
+
+def _placed(state) -> jax.Array:
+    """(token, expert) pairs that reached an expert slot on this rank."""
+    return jnp.sum(state.map_exp >= 0, dtype=jnp.int32)
+
+
 def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
             capacity_factor: float | None = None):
-    """x: [B, S, D] -> ([B, S, D], aux_loss).  params from init_moe."""
+    """x: [B, S, D] -> ([B, S, D], aux_loss, dropped).  params from
+    init_moe.  ``dropped`` [R] int32, one count per data-parallel rank
+    (R = 1 without a mesh), adds up to the (token, expert) pairs of the
+    real rows that found no slot at any stage of the dispatch; it is
+    summed where it is read, so that counting adds no collective."""
     b, s, d = x.shape
     dt = x.dtype
     if capacity_factor is None:
         capacity_factor = cfg.moe_capacity
-    tokens_in = x.reshape(b * s, d)
+    n = b * s
+    tokens_in = x.reshape(n, d)
 
     if pctx is None:
         epmesh = cl.EPMesh(pod_axis=None, ep_axis="_none", num_pods=1,
                            ep_per_pod=1)
-        dcfg = balanced_capacities(b * s, cfg.top_k, 1, 1, cfg.num_experts,
+        dcfg = balanced_capacities(n, cfg.top_k, 1, 1, cfg.num_experts,
                                    capacity_factor)
-        out, aux = _moe_local(params, tokens_in, cfg, dcfg, epmesh)
-        return out.reshape(b, s, d).astype(dt), aux
+        out, aux, dropped = _moe_local(params, tokens_in, cfg, dcfg, epmesh)
+        return out.reshape(b, s, d).astype(dt), aux, dropped
 
     use_pod, _ = pctx.ep_ranks(cfg.num_experts)
     p = pctx.num_pods if use_pod else 1
@@ -160,43 +229,31 @@ def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
     ep_spec = ((pctx.pod_axis, pctx.data_axis) if use_pod
                else (pctx.data_axis,))
     dp_spec = pctx.dp_axes
-    n_local = (b * s) // (pctx.num_pods * pctx.data_size)
+    plan = plan_moe(cfg, pctx, n, d, params["w1"].shape[-1],
+                    x.dtype.itemsize)
+    n_local = plan.n_local
+    # capacities follow the rows per rank, which padding does not raise
+    # past the real rows of the fullest rank: padded rows take no slot
     dcfg = balanced_capacities(n_local, cfg.top_k, p, dd, per_rank,
                                capacity_factor)
-    # The WHOLE round trip — dispatch scheme, return-path scheme and the
-    # shared pipeline chunk count G — is one jointly-planned decision:
-    # a bound ExecutionPlan resolves it by declared-site lookup; under
-    # plan_policy="auto" without a bound plan the same joint sweep runs
-    # ad hoc (§5.2 dynamic workflow — decode traces pick the unicast
-    # pair at small batch, prefill/train cross to MultiWrite, and the
-    # shared-pipeline scorer picks the G where overlapping
-    # dispatch/compute/combine chunks beats BOTH halves' per-chunk
-    # launch alphas); the declared moe_scheme/moe_combine/moe_microbatch
-    # knobs apply under "fixed".
-    from repro.core.latency_model import moe_overlap_compute_s
-    compute_s = moe_overlap_compute_s(n_local, cfg.top_k, d,
-                                      params["w1"].shape[-1],
-                                      tp=pctx.model_size)
-    pipe_kw = pctx.moe_pipeline_kwargs(
-        cfg.num_experts, cfg.top_k, tokens_per_rank=n_local,
-        token_bytes=d * x.dtype.itemsize, compute_s=compute_s)
-    # the chosen G must divide the local token count; gcd clamps it to
-    # the largest divisor <= G (pow-2 grids always divide pow-2 batches).
-    # A clamp re-resolves the configuration AT the executed G: the
-    # scheme pair is taken from the joint sweep's candidates at the
-    # depth the pipeline actually runs, not one it never honors.
-    microbatch = math.gcd(max(1, int(pipe_kw["microbatch"])),
-                          n_local) or 1
-    if microbatch != int(pipe_kw["microbatch"]):
-        pipe_kw = pctx.moe_pipeline_kwargs(
-            cfg.num_experts, cfg.top_k, tokens_per_rank=n_local,
-            token_bytes=d * x.dtype.itemsize, compute_s=compute_s,
-            microbatch=microbatch)
-    scheme = pipe_kw["moe_scheme"]
-    combine_scheme = pipe_kw["moe_combine"]
-    if scheme == "baseline":
+    if plan.dispatch == "baseline":
         dcfg = unicast_capacities(dcfg, n_local, cfg.top_k, p * dd,
                                   per_rank, capacity_factor)
+    # rows that do not divide over the data-parallel ranks are padded to
+    # the next multiple and masked out of the dispatch; where they divide
+    # (decided here, on static shapes) the program has no pad, mask or
+    # slice at all
+    if plan.pad_rows:
+        tokens_in = jnp.pad(tokens_in, ((0, plan.pad_rows), (0, 0)))
+
+    def real_rows(k, rows):
+        """[rows] bool: which rows of this rank's chunk ``k`` are real
+        (padding sits past row ``n`` of the whole batch); None where
+        nothing is padded."""
+        if not plan.pad_rows:
+            return None
+        first = jax.lax.axis_index(dp_spec) * n_local + k * rows
+        return first + jnp.arange(rows) < n
 
     # deferred TP reduction: the combine tree is LINEAR in the expert
     # outputs, so the row-parallel psum commutes through it — emit partial
@@ -216,26 +273,34 @@ def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
     # finishing the previous one, so the dispatch collectives of chunk
     # k+1 have no data dependency on the FFN/combine of chunk k and the
     # compiler overlaps them (double buffering: one chunk in flight).
+    # Each chunk also counts the (token, expert) pairs its real rows
+    # ask for less those that reached an expert slot on this rank;
+    # summed over the ranks, that is what the dispatch dropped.
 
-    def dispatch_chunk(tok, router, w1, w3, w2):
+    def dispatch_chunk(tok, k, router, w1, w3, w2):
         logits = tok.astype(jnp.float32) @ router
         gates, ids = cl.route_topk(logits, cfg.top_k)
         aux = load_balance_loss(logits, ids, cfg.num_experts)
         aux = jax.lax.pmean(aux, dp_spec)
-        dispatch_fn = (cl.hierarchical_dispatch if scheme == "hierarchical"
+        dispatch_fn = (cl.hierarchical_dispatch
+                       if plan.dispatch == "hierarchical"
                        else cl.baseline_dispatch)
+        valid = real_rows(k, tok.shape[0])
         with jax.named_scope("moe.dispatch"):
             exp_tok, exp_gate, st = dispatch_fn(tok, ids, gates, dcfg,
-                                                epmesh)
-        return (exp_tok, exp_gate, st), aux
+                                                epmesh, valid)
+        rows = (tok.shape[0] if valid is None
+                else jnp.sum(valid, dtype=jnp.int32))
+        dropped = rows * cfg.top_k - _placed(st)
+        return (exp_tok, exp_gate, st), aux, dropped
 
     def finish_chunk(pack, w1, w3, w2, out_dtype):
         exp_tok, exp_gate, st = pack
         with jax.named_scope("moe.expert_ffn"):
             exp_out = _expert_ffn(w1, w3, w2, exp_tok, cfg.act, expert_axis)
-        if scheme == "hierarchical":
+        if plan.dispatch == "hierarchical":
             combine_fn = (cl.hierarchical_combine
-                          if combine_scheme == "hierarchical"
+                          if plan.combine == "hierarchical"
                           else cl.hierarchical_combine_unicast)
         else:
             combine_fn = cl.baseline_combine
@@ -245,14 +310,12 @@ def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
                 out = jax.lax.psum(out, pctx.model_axis)
         return out.astype(out_dtype)
 
-    def one_chunk(tok, router, w1, w3, w2):
-        pack, aux = dispatch_chunk(tok, router, w1, w3, w2)
-        return finish_chunk(pack, w1, w3, w2, tok.dtype), aux
-
     def inner(tok, router, w1, w3, w2):
-        g = microbatch
+        g = plan.g
         if g <= 1:
-            return one_chunk(tok, router, w1, w3, w2)
+            pack, aux, dropped = dispatch_chunk(tok, 0, router, w1, w3, w2)
+            out = finish_chunk(pack, w1, w3, w2, tok.dtype)
+            return out, aux, dropped[None]
         n_loc, h = tok.shape
         assert n_loc % g == 0, (n_loc, g)
         chunks = tok.reshape(g, n_loc // g, h)
@@ -260,33 +323,41 @@ def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
         # dispatches chunk k+1 FIRST, then finishes chunk k — per-chunk
         # results are identical to the serial loop (bit-exact), only the
         # issue order changes, which is what lets the dispatch traffic
-        # hide behind the previous chunk's expert FFN + combine.
-        pack0, aux0 = dispatch_chunk(chunks[0], router, w1, w3, w2)
+        # hide behind the previous chunk's expert FFN + combine.  The
+        # chunk index rides along only where rows are masked.
+        pack0, aux0, drop0 = dispatch_chunk(chunks[0], 0, router,
+                                            w1, w3, w2)
 
-        def body(carry, tok_next):
-            pack_next, aux_next = dispatch_chunk(tok_next, router,
-                                                 w1, w3, w2)
+        def body(carry, xs):
+            tok_next, k = xs
+            pack_next, aux_next, drop_next = dispatch_chunk(
+                tok_next, k, router, w1, w3, w2)
             out_prev = finish_chunk(carry, w1, w3, w2, tok.dtype)
-            return pack_next, (out_prev, aux_next)
+            return pack_next, (out_prev, aux_next, drop_next)
 
-        pack_last, (outs, auxs) = jax.lax.scan(body, pack0, chunks[1:])
+        ks = jnp.arange(1, g) if plan.pad_rows else None
+        pack_last, (outs, auxs, drops) = jax.lax.scan(
+            body, pack0, (chunks[1:], ks))
         out_last = finish_chunk(pack_last, w1, w3, w2, tok.dtype)
         out = jnp.concatenate([outs.reshape(n_loc - n_loc // g, h),
                                out_last], axis=0)
-        return out, (aux0 + jnp.sum(auxs)) / g
+        dropped = drop0 + jnp.sum(drops)
+        return out, (aux0 + jnp.sum(auxs)) / g, dropped[None]
 
-    out, aux = shard_map(
+    out, aux, dropped = shard_map(
         inner, mesh=pctx.mesh,
         in_specs=(P(dp_spec, None),            # tokens split over DP ranks
                   P(None, None),               # router replicated
                   P(ep_spec, None, pctx.model_axis),
                   P(ep_spec, None, pctx.model_axis),
                   P(ep_spec, pctx.model_axis, None)),
-        out_specs=(P(dp_spec, None), P()),
+        out_specs=(P(dp_spec, None), P(), P(dp_spec)),
         check_vma=False,
     )(tokens_in, params["router"].astype(jnp.float32),
       params["w1"], params["w3"], params["w2"])
-    return out.reshape(b, s, d).astype(dt), aux
+    if plan.pad_rows:
+        out = out[:n]
+    return out.reshape(b, s, d).astype(dt), aux, dropped
 
 
 def _moe_local(params, tokens, cfg, dcfg, epmesh):
@@ -298,4 +369,5 @@ def _moe_local(params, tokens, cfg, dcfg, epmesh):
         tokens, ids, gates, dcfg, epmesh)
     exp_out = _expert_ffn(params["w1"], params["w3"], params["w2"],
                           exp_tok, cfg.act, None)
-    return cl.hierarchical_combine(exp_out, exp_gate, st), aux
+    dropped = tokens.shape[0] * cfg.top_k - _placed(st)
+    return cl.hierarchical_combine(exp_out, exp_gate, st), aux, dropped[None]

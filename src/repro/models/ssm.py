@@ -269,7 +269,7 @@ def zamba2_prefill(params, cfg, pctx, x, state):
             a, (k, v) = _attn_part(params["shared"], x, positions, cfg,
                                    pctx, window=None, return_kv=True)
             x = x + a
-            f, _ = _ffn_part(params["shared"], x, cfg, pctx)
+            f, _, _ = _ffn_part(params["shared"], x, cfg, pctx)
             x = x + f
             pad = ks[si].shape[1] - k.shape[1]
             if pad:
@@ -303,7 +303,7 @@ def zamba2_decode_step(params, cfg, pctx, x, state):
             a, ck, cv = _decode_attn(params["shared"], x, ks[si], vs[si],
                                      cur, cfg, pctx, window=None)
             x = x + a
-            f, _ = _ffn_part(params["shared"], x, cfg, pctx)
+            f, _, _ = _ffn_part(params["shared"], x, cfg, pctx)
             x = x + f
             ks[si], vs[si] = ck, cv
             si += 1

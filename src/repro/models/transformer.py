@@ -153,17 +153,21 @@ def _cross_attention(p, x, enc_out, cfg, pctx):
 
 
 def _ffn_part(lp, x, cfg, pctx):
+    """(out, aux loss, dropped): ``dropped`` is the MoE layer's per-rank
+    count of (token, expert) pairs that found no slot (``moe_ffn``), and
+    None in a dense layer."""
     h = L.rmsnorm(lp["ln2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
+    dropped = None
     if "moe" in lp:
-        out, aux = M.moe_ffn(lp["moe"], h, cfg, pctx)
+        out, aux, dropped = M.moe_ffn(lp["moe"], h, cfg, pctx)
         if "shared_mlp" in lp:
             out = out + L.mlp(lp["shared_mlp"], h, cfg.act, pctx)
     else:
         out = L.mlp(lp["mlp"], h, cfg.act, pctx)
     if cfg.post_norm:
         out = L.rmsnorm(lp["pn2"], out, cfg.norm_eps)
-    return out, aux
+    return out, aux, dropped
 
 
 def _split_tp_seq_gather(x, pctx: Optional[ParallelContext]):
@@ -220,7 +224,7 @@ def _dense_block(lp, x, positions, cfg, pctx, *, window):
     x = _split_tp_seq_gather(x, pctx)
     a = _attn_part(lp, x, positions, cfg, pctx, window=window)
     x = x + a
-    f, aux = _ffn_part(lp, x, cfg, pctx)
+    f, aux, _ = _ffn_part(lp, x, cfg, pctx)
     x = x + f
     x = shard_residual(x, pctx)
     return x, aux
@@ -284,7 +288,7 @@ def encode(params, cfg, pctx, src_embeds):
             a = _attn_part(lp_, x_, positions, cfg, pctx, window=None,
                            causal=False)
             x_ = x_ + a
-            f, _ = _ffn_part(lp_, x_, cfg, pctx)
+            f, _, _ = _ffn_part(lp_, x_, cfg, pctx)
             return x_ + f
 
         return _remat(inner, pctx)(lp, carry), None
@@ -304,7 +308,7 @@ def forward_hidden_encdec(params, cfg, pctx, tgt_embeds, positions, enc_out):
             if cfg.post_norm:
                 xa = L.rmsnorm(lp_["pnx"], xa, cfg.norm_eps)
             x_ = x_ + xa
-            f, _ = _ffn_part(lp_, x_, cfg, pctx)
+            f, _, _ = _ffn_part(lp_, x_, cfg, pctx)
             return x_ + f
 
         return _remat(inner, pctx)(lp, carry), None
@@ -348,7 +352,7 @@ def prefill(params, cfg, pctx, x, positions, cache):
                                window=None if wins is None else wins[idx],
                                return_kv=True)
         x = x + a
-        f, _ = _ffn_part(lp, x, cfg, pctx)
+        f, _, _ = _ffn_part(lp, x, cfg, pctx)
         x = x + f
         new_k.append(jax.lax.dynamic_update_slice(
             cache["k"][idx], k.astype(cdt), (0, 0, 0, 0)))
@@ -364,12 +368,12 @@ def prefill(params, cfg, pctx, x, positions, cache):
         a, (k, v) = _attn_part(lp, x, positions, cfg, pctx, window=win,
                                return_kv=True)
         x = x + a
-        f, _ = _ffn_part(lp, x, cfg, pctx)
-        return x + f, (k.astype(cdt), v.astype(cdt))
+        f, _, dropped = _ffn_part(lp, x, cfg, pctx)
+        return x + f, (k.astype(cdt), v.astype(cdt), dropped)
 
     win_xs = (jnp.full((n_scan,), BIG_WINDOW, jnp.int32) if wins is None
               else wins[offset:])
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], win_xs))
+    x, (ks, vs, dropped) = jax.lax.scan(body, x, (params["layers"], win_xs))
     pad = max_len - seq
     for li in range(n_scan):
         k_full = (jnp.pad(ks[li], ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -380,8 +384,18 @@ def prefill(params, cfg, pctx, x, positions, cache):
         new_v.append(v_full)
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_fn(params, cfg, x, last_only=True)
-    return logits, {"k": tuple(new_k), "v": tuple(new_v),
-                    "len": jnp.asarray(seq, jnp.int32)}
+    cache = {"k": tuple(new_k), "v": tuple(new_v),
+             "len": jnp.asarray(seq, jnp.int32)}
+    return logits, cache, _step_stats(
+        None if dropped is None else jnp.sum(dropped, axis=0))
+
+
+def _step_stats(dropped) -> dict:
+    """The device counters a prefill or decode step returns beside its
+    logits and cache: ``moe_dropped``, the MoE layers' summed per-rank
+    counts of (token, expert) pairs without a slot, where the stack has
+    MoE layers; empty (no program output) for a dense stack."""
+    return {} if dropped is None else {"moe_dropped": dropped}
 
 
 def decode_step(params, cfg, pctx, x, cache):
@@ -399,13 +413,14 @@ def decode_step(params, cfg, pctx, x, cache):
                                  pctx,
                                  window=None if wins is None else wins[idx])
         x = x + a
-        f, _ = _ffn_part(lp, x, cfg, pctx)
+        f, _, _ = _ffn_part(lp, x, cfg, pctx)
         x = x + f
         new_k[idx], new_v[idx] = ck, cv
         idx += 1
 
     offset = cfg.first_k_dense if cfg.is_moe else 0
     n_scan = cfg.n_layers - offset
+    dropped = None
     for li in range(n_scan):
         lp = jax.tree_util.tree_map(lambda a: a[li], params["layers"])
         win = None if wins is None else wins[offset + li]
@@ -413,12 +428,15 @@ def decode_step(params, cfg, pctx, x, cache):
                                  new_v[offset + li], cur, cfg, pctx,
                                  window=win)
         x = x + a
-        f, _ = _ffn_part(lp, x, cfg, pctx)
+        f, _, d = _ffn_part(lp, x, cfg, pctx)
+        if d is not None:
+            dropped = d if dropped is None else dropped + d
         x = x + f
         new_k[offset + li], new_v[offset + li] = ck, cv
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_fn(params, cfg, x, last_only=True)
-    return logits, {"k": tuple(new_k), "v": tuple(new_v), "len": cur + 1}
+    return (logits, {"k": tuple(new_k), "v": tuple(new_v), "len": cur + 1},
+            _step_stats(dropped))
 
 
 def _decode_attn(lp, x, ck, cv, cur, cfg, pctx, *, window):
@@ -455,7 +473,7 @@ def prefill_encdec(params, cfg, pctx, src_embeds, tgt_embeds, positions,
         if cfg.post_norm:
             xa = L.rmsnorm(lp["pnx"], xa, cfg.norm_eps)
         x = x + xa
-        f, _ = _ffn_part(lp, x, cfg, pctx)
+        f, _, _ = _ffn_part(lp, x, cfg, pctx)
         return x + f, (k.astype(cdt), v.astype(cdt))
 
     x, (ks, vs) = jax.lax.scan(body, tgt_embeds, params["layers"])
@@ -497,7 +515,7 @@ def decode_step_encdec(params, cfg, pctx, x, cache):
         if cfg.post_norm:
             xa = L.rmsnorm(lp["pnx"], xa, cfg.norm_eps)
         x = x + xa
-        f, _ = _ffn_part(lp, x, cfg, pctx)
+        f, _, _ = _ffn_part(lp, x, cfg, pctx)
         x = x + f
         new_k[li], new_v[li] = ck, cv
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)

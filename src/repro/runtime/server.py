@@ -41,14 +41,29 @@ class _ServeLowering:
     """Traced-lowering artifact of one ExecutionPlan: the jitted
     prefill/decode pair whose MoE layers consumed exactly that plan's
     decisions at trace time, plus the bound context they read them
-    from, and the jitted cache builder with the cache shapes it has
-    compiled (``(batch, max_len, dtype)``)."""
+    from, the jitted cache builder with the cache shapes it has
+    compiled (``(batch, max_len, dtype)``), and the MoE plan its layers
+    run per token count, as far as the spans have asked for it."""
     pctx: object
     model: object
     prefill: Callable
     decode: Callable
     init_cache: Callable
     cache_shapes: set = dataclasses.field(default_factory=set)
+    moe_plans: dict = dataclasses.field(default_factory=dict)
+
+
+def _moe_attrs(lowering: _ServeLowering, n_tokens: int, stats) -> dict:
+    """Span attributes of an MoE launch of ``n_tokens`` token rows: the
+    (token, expert) pairs its dispatch dropped (the per-rank counts of
+    the step's ``stats``, read after ``engine.wait``, when the device
+    has finished them) and the plan its layers ran."""
+    plan = lowering.moe_plans.get(n_tokens)
+    if plan is None:
+        plan = lowering.moe_plans[n_tokens] = lowering.model.moe_plan(
+            n_tokens)
+    return {"moe_dropped": int(np.asarray(stats["moe_dropped"]).sum()),
+            **plan.attrs()}
 
 
 @dataclasses.dataclass
@@ -348,7 +363,7 @@ class ServeEngine:
         cohort = next(self._cohort_ids)
         t0 = time.monotonic()
         with spans.span("engine.prefill", batch=b, prompt_len=s,
-                        cohort=cohort):
+                        cohort=cohort) as sp:
             shape = (b, int(s + max_new), self.cfg.cache_dtype)
             # first: this lowering's builder compiles for the shape now
             with spans.span("engine.cache_init",
@@ -361,30 +376,36 @@ class ServeEngine:
                     cfg, {"tokens": prompts, "labels": prompts})
                 batch.pop("labels", None)
             with spans.span("engine.launch"):
-                logits, cache = self._prefill(self.params, batch, cache)
+                logits, cache, stats = lowering.prefill(
+                    self.params, batch, cache)
                 state = CohortState(cache=cache, logits=logits,
                                     key=jax.random.key(seed), batch=b,
                                     cohort=cohort)
                 sampled = self._sample(state)
             with spans.span("engine.wait"):
                 tokens = np.asarray(sampled, np.int32)
+            if stats and spans.recording():
+                sp.set(**_moe_attrs(lowering, b * s, stats))
         return state, tokens, time.monotonic() - t0
 
     def step_cohort(self, state: "CohortState", tokens: np.ndarray):
         """One decode round: consume the cohort's last sampled tokens,
         sample the next.  Returns ``(state, tokens, wall_s)``."""
         t0 = time.monotonic()
+        lowering = self._binder.artifact
         with spans.span("engine.decode", batch=state.batch,
-                        cohort=state.cohort):
+                        cohort=state.cohort) as sp:
             with spans.span("engine.embed"):
                 dec_in = self._decode_batch(
                     np.asarray(tokens, np.int32)[:, None])
             with spans.span("engine.launch"):
-                state.logits, state.cache = self._decode(
+                state.logits, state.cache, stats = lowering.decode(
                     self.params, dec_in, state.cache)
                 sampled = self._sample(state)
             with spans.span("engine.wait"):
                 tokens = np.asarray(sampled, np.int32)
+            if stats and spans.recording():
+                sp.set(**_moe_attrs(lowering, state.batch, stats))
         return state, tokens, time.monotonic() - t0
 
     def _sample(self, state: "CohortState"):

@@ -125,6 +125,12 @@ def span(name: str, **attrs):
     return _Span(name, attrs) if _room(name) else _NULL
 
 
+def recording() -> bool:
+    """Whether spans are recorded now: an attribute that costs work to
+    compute (a read from the device) is computed only then."""
+    return _on
+
+
 def begin(name: str, key, **attrs) -> None:
     """Open a span that a later call closes with :func:`end` under the
     same ``name`` and ``key``."""

@@ -248,15 +248,16 @@ def run_moe_pipeline_checks():
               ("hierarchical", "baseline"),
               ("baseline", "baseline")]
     for scheme, combine in combos:
-        outs, auxs = {}, {}
+        outs, auxs, drops = {}, {}, {}
         for g in (1, 4):
             pctx = dataclasses.replace(base, moe_scheme=scheme,
                                        moe_combine=combine,
                                        moe_microbatch=g)
             with mesh:
-                out, aux = jax.jit(
+                out, aux, dropped = jax.jit(
                     lambda xx, p=pctx: moe_ffn(params, xx, cfg, p))(x)
             outs[g], auxs[g] = np.asarray(out), float(aux)
+            drops[g] = int(np.sum(dropped))
         ok = np.array_equal(outs[1], outs[4])
         err = float(np.max(np.abs(outs[1] - outs[4])))
         check(f"moe_ffn pipelined G=4 bit-exact vs G=1 "
@@ -264,6 +265,9 @@ def run_moe_pipeline_checks():
         check(f"moe_ffn pipelined aux finite "
               f"(dispatch={scheme}, combine={combine})",
               np.isfinite(auxs[4]) and np.isfinite(auxs[1]))
+        check(f"moe_ffn drops nothing at capacity 4.0, G=1 and G=4 "
+              f"(dispatch={scheme}, combine={combine}, dropped={drops})",
+              drops == {1: 0, 4: 0})
 
 
 # ===========================================================================
@@ -295,7 +299,7 @@ def run_execution_plan_checks():
 
     def run(pctx):
         with mesh:
-            out, aux = jax.jit(
+            out, aux, _ = jax.jit(
                 lambda xx, p=pctx: moe_ffn(params, xx, cfg, p))(x)
         return np.asarray(out), float(aux)
 

@@ -377,15 +377,15 @@ class TestContextCombine:
 
         pctx_auto = _mesh_pctx(plan_policy="auto",
                                fabric=two_server_cluster())
-        out_auto, aux = moe.moe_ffn(params, x, cfg, pctx_auto)
+        out_auto, aux, _ = moe.moe_ffn(params, x, cfg, pctx_auto)
         assert out_auto.shape == x.shape
         assert np.isfinite(np.asarray(out_auto)).all()
 
         # fixed hierarchical dispatch, both combine lowerings
         pctx_h = _mesh_pctx(moe_scheme="hierarchical")
         pctx_hu = dataclasses.replace(pctx_h, moe_combine="baseline")
-        out_h, _ = moe.moe_ffn(params, x, cfg, pctx_h)
-        out_hu, _ = moe.moe_ffn(params, x, cfg, pctx_hu)
+        out_h, _, _ = moe.moe_ffn(params, x, cfg, pctx_h)
+        out_hu, _, _ = moe.moe_ffn(params, x, cfg, pctx_hu)
         np.testing.assert_allclose(np.asarray(out_h), np.asarray(out_hu),
                                    atol=1e-5)
 
