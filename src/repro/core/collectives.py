@@ -27,6 +27,9 @@ MoE dispatch/combine (paper §3.2 / §6.3):
   * :func:`route_topk`            — gate -> (gates, expert ids).
   * :func:`pack_by_bitmap`        — bitmap-driven send-buffer packing; the
     pure-jnp twin of the Pallas ``dispatch_pack`` kernel (cs_send).
+  * :func:`dispatch_slots`        — each stage's slot count, a share of
+    the rows that can arrive there at most (past it is refused).
+  * :func:`capped_capacities`     — headroom capped at those worst cases.
   * :func:`hierarchical_dispatch` — MultiWrite dispatch: one copy per
     (token, remote pod), relay replication intra-pod.
   * :func:`baseline_dispatch`     — unicast dispatch: one copy per
@@ -337,12 +340,75 @@ class EPMesh:
 
 @dataclasses.dataclass(frozen=True)
 class DispatchConfig:
+    """Slots of each dispatch stage, as a share (at most 1) of the rows
+    that can arrive there at most: the stage's no-drop worst case.  A
+    share of 1 drops nothing whatever the routing; below 1, rows past
+    the slots are dropped in token order.  The dispatch refuses a share
+    past 1; :func:`capped_capacities` turns headroom, whose shares may
+    pass 1, into shares of the worst case."""
     num_experts: int
     top_k: int
-    # capacity factors are vs. the no-drop worst case of each stage
-    pod_capacity: float = 1.0   # stage-1 buffer = N * pod_capacity
-    ep_capacity: float = 1.0    # stage-2 buffer = P*Cp*ep_capacity / D... see code
+    # stage 1 (MultiWrite, per pod) / unicast (per rank): of the N rows,
+    # each sent once per destination at most
+    pod_capacity: float = 1.0
+    # stage 2 (MultiWrite, per ep rank): of the P*Cp stage-1 slots that
+    # land on a relay, each forwarded once per ep rank at most
+    ep_capacity: float = 1.0
+    # per local expert: of the rows that reach the rank (D*Cd MultiWrite,
+    # R*Cr unicast), each routed to an expert once at most
     expert_capacity: float = 1.0
+
+
+def _stages(cfg: DispatchConfig, mesh: EPMesh, unicast: bool):
+    """Each dispatch stage as (name, fan-in, share): the most rows that
+    can arrive there are its fan-in times the slots of the stage before
+    it (the ``n`` rows a chip, before the first)."""
+    p, d = mesh.num_pods, mesh.ep_per_pod
+    if unicast:
+        return (("per-rank", 1, cfg.pod_capacity),
+                ("expert", p * d, cfg.expert_capacity))
+    return (("stage-1", 1, cfg.pod_capacity),
+            ("stage-2", p, cfg.ep_capacity),
+            ("expert", d, cfg.expert_capacity))
+
+
+def dispatch_slots(cfg: DispatchConfig, mesh: EPMesh, n: int,
+                   unicast: bool = False) -> tuple[int, ...]:
+    """Slots each stage allocates for ``n`` rows a chip, at least one:
+    (Cp, Cd, Ce) for :func:`hierarchical_dispatch`, (Cr, Ce) for the
+    ``unicast`` :func:`baseline_dispatch`.  A share that would allocate
+    past the stage's worst case is refused: such slots could never
+    fill."""
+    slots, prev = [], n
+    for stage, fan, share in _stages(cfg, mesh, unicast):
+        worst = fan * prev
+        prev = max(1, int(round(worst * share)))
+        if prev > worst:
+            raise ValueError(f"{stage} capacity {share} allocates {prev} "
+                             f"slots where at most {worst} rows can arrive")
+        slots.append(prev)
+    return tuple(slots)
+
+
+def capped_capacities(headroom: DispatchConfig, mesh: EPMesh, n: int,
+                      unicast: bool = False) -> DispatchConfig:
+    """``headroom`` capped at each stage's no-drop worst case, for ``n``
+    rows a chip.  Each stage wants the slots ``headroom``'s share gives
+    of the uncapped slots before it, and gets at most the rows that can
+    arrive, given the capped slots before it: wherever that wish is
+    within the worst case it is kept, and only slots that no routing
+    can fill go.  :func:`dispatch_slots` gives the capped slots back for
+    ``n`` rows."""
+    want = have = n
+    shares = []
+    for _, fan, share in _stages(headroom, mesh, unicast):
+        want = max(1, int(round(fan * want * share)))
+        worst = fan * have
+        have = min(want, worst)
+        shares.append(have / worst)
+    fields = (("pod_capacity", "expert_capacity") if unicast else
+              ("pod_capacity", "ep_capacity", "expert_capacity"))
+    return dataclasses.replace(headroom, **dict(zip(fields, shares)))
 
 
 def expert_placement(cfg: DispatchConfig, mesh: EPMesh):
@@ -397,7 +463,7 @@ def hierarchical_dispatch(tokens: jax.Array, expert_ids: jax.Array,
     ep_bits = jnp.sum(
         ep_any.astype(jnp.int32) << jnp.arange(d), axis=-1).astype(jnp.int32)
 
-    cp = max(1, int(round(n * cfg.pod_capacity)))
+    cp, cd, ce = dispatch_slots(cfg, mesh, n)
     if valid is None:
         valid = jnp.ones((n,), bool)
     send_tok, map_pod = pack_by_bitmap(tokens, pod_bits, valid, p, cp)
@@ -427,7 +493,6 @@ def hierarchical_dispatch(tokens: jax.Array, expert_ids: jax.Array,
     flat_tok = recv_tok.reshape(p * cp, h)
     flat_ep = recv_ep.reshape(p * cp)
     flat_valid = (recv_src.reshape(p * cp) >= 0)
-    cd = max(1, int(round(p * cp * cfg.ep_capacity)))
     relay_tok, map_ep = pack_by_bitmap(flat_tok, flat_ep, flat_valid, d, cd)
     relay_ids = gather_rows(recv_ids.reshape(p * cp, k), map_ep.reshape(-1)
                             ).reshape(d, cd, k)
@@ -459,7 +524,6 @@ def hierarchical_dispatch(tokens: jax.Array, expert_ids: jax.Array,
     ).astype(jnp.int32)
     # OR-safety: top-k ids are distinct -> a token hits each local expert at
     # most once -> sum == OR.  (Routers guarantee distinct ids.)
-    ce = max(1, int(round(d * cd * cfg.expert_capacity)))
     exp_tok, map_exp = pack_by_bitmap(flat2_tok, exp_bits, flat2_valid,
                                       per_rank, ce)
     exp_gate = _gate_for_expert(flat2_ids, flat2_gates, map_exp,
@@ -631,7 +695,7 @@ def baseline_dispatch(tokens: jax.Array, expert_ids: jax.Array,
     rank_bits32 = [jnp.sum(rank_any[:, w * 31:(w + 1) * 31].astype(jnp.int32)
                            << jnp.arange(min(31, r - w * 31)), axis=-1)
                    for w in range((r + 30) // 31)]
-    cr = max(1, int(round(n * cfg.pod_capacity)))
+    cr, ce = dispatch_slots(cfg, mesh, n, unicast=True)
     if valid is None:
         valid = jnp.ones((n,), bool)
     # pack per rank using multi-word bitmaps
@@ -673,7 +737,6 @@ def baseline_dispatch(tokens: jax.Array, expert_ids: jax.Array,
     mine = (local_e >= 0) & (local_e < per_rank)
     exp_bits = jnp.sum(jnp.where(mine, 1 << jnp.clip(local_e, 0, 30), 0),
                        axis=-1).astype(jnp.int32)
-    ce = max(1, int(round(r * cr * cfg.expert_capacity)))
     exp_tok, map_exp = pack_by_bitmap(flat_tok, exp_bits,
                                       got_valid.reshape(r * cr), per_rank, ce)
     exp_gate = _gate_for_expert(flat_ids, flat_gates, map_exp,

@@ -86,42 +86,38 @@ def _expert_ffn(w1, w3, w2, x, act_name: str, model_axis: str | None):
 
 
 def balanced_capacities(n_tokens: int, k: int, p: int, d: int,
-                        per_rank: int, cf: float) -> cl.DispatchConfig:
+                        per_rank: int, cf: float,
+                        unicast: bool = False) -> cl.DispatchConfig:
     """Capacity factors sized from *balanced-routing expectations* (the
     paper evaluates with load balancing on, §6.1), with headroom ``cf``:
 
       stage-1 slots/pod     ~ N * min(1, k/p)
       stage-2 slots/ep rank ~ (arrivals p*Cp) * min(1, (k/p)/d)
       expert slots          ~ N*k/per_rank  (total (token,expert) pairs)
-    """
+
+    ``unicast`` sizes the per-destination-RANK packing of
+    ``baseline_dispatch`` instead: the balanced per-rank expectation
+    (k/R over R = p*d ranks), and ``expert_capacity`` — a share of the
+    incoming buffer — renormalized from the hierarchical D*Cd buffer to
+    the unicast R*Cr one, or small decode batches round the expert
+    buffer down to zero slots.  The factors may pass 1, and
+    :func:`repro.core.collectives.capped_capacities` caps each stage at
+    the rows that can arrive before a dispatch runs them."""
+    ce_target = max(1, int(round(n_tokens * k / per_rank * cf)))
+    if unicast:
+        rank_cap = min(1.0, k / (p * d)) * cf
+        cr = max(1, int(round(n_tokens * rank_cap)))
+        return cl.DispatchConfig(num_experts=per_rank * p * d, top_k=k,
+                                 pod_capacity=rank_cap,
+                                 expert_capacity=ce_target / (p * d * cr))
     pod_cap = min(1.0, k / p) * cf
     cp = max(1, int(round(n_tokens * pod_cap)))
     ep_cap = min(1.0, (k / p) / d) * cf
     cd = max(1, int(round(p * cp * ep_cap)))
-    ce_target = max(1, int(round(n_tokens * k / per_rank * cf)))
     exp_cap = ce_target / (d * cd)
     return cl.DispatchConfig(num_experts=per_rank * p * d, top_k=k,
                              pod_capacity=pod_cap, ep_capacity=ep_cap,
                              expert_capacity=exp_cap)
-
-
-def unicast_capacities(dcfg: cl.DispatchConfig, n_tokens: int, k: int,
-                       ranks: int, per_rank: int,
-                       cf: float) -> cl.DispatchConfig:
-    """Rebase a :func:`balanced_capacities` config for the UNICAST
-    (per-destination-RANK) packing of ``baseline_dispatch``: fair
-    capacity is the balanced per-rank expectation (k/R), and
-    ``expert_capacity`` — a fraction of the incoming buffer — must be
-    renormalized from the hierarchical D*Cd buffer to the unicast R*Cr
-    one, or small decode batches round the expert buffer down to zero
-    slots.  Kept next to its hierarchical twin so the two sizing rules
-    (which both anticipate the callee's ``max(1, round(...))``) evolve
-    together."""
-    rank_cap = min(1.0, k / ranks) * cf
-    cr = max(1, int(round(n_tokens * rank_cap)))
-    ce_target = max(1, int(round(n_tokens * k / per_rank * cf)))
-    return dataclasses.replace(dcfg, pod_capacity=rank_cap,
-                               expert_capacity=ce_target / (ranks * cr))
 
 
 def load_balance_loss(logits, ids, num_experts: int):
@@ -137,26 +133,50 @@ def load_balance_loss(logits, ids, num_experts: int):
 class MoEPlan:
     """What one MoE layer runs for a token count, decided on static
     shapes: the dispatch and return-path schemes, the pipeline chunk
-    count G, and the rows padded on so that the data-parallel ranks
-    split the tokens evenly (0 where they divide)."""
+    count G, the rows padded on so that the data-parallel ranks split
+    the tokens evenly (0 where they divide), the dispatch capacities of
+    one chunk and the expert-parallel mesh they run on."""
     dispatch: str            # "hierarchical" (MultiWrite) or "baseline"
     combine: str             # "hierarchical" (relay-reduced) or "baseline"
     g: int
     pad_rows: int
     n_local: int             # token rows per rank, padding included
+    dcfg: cl.DispatchConfig
+    mesh: cl.EPMesh
+
+    @property
+    def slots(self) -> tuple[int, ...]:
+        """Slots each dispatch stage allocates per chunk a chip: (Cp,
+        Cd, Ce) MultiWrite, (Cr, Ce) unicast."""
+        return cl.dispatch_slots(self.dcfg, self.mesh, self.n_local // self.g,
+                                 unicast=self.dispatch == "baseline")
 
     def attrs(self) -> dict:
         """The plan as span attributes (``engine.prefill`` /
         ``engine.decode``)."""
         return {"moe_dispatch": self.dispatch, "moe_combine": self.combine,
-                "moe_g": self.g, "ep_pad_rows": self.pad_rows}
+                "moe_g": self.g, "ep_pad_rows": self.pad_rows,
+                "moe_slots": "/".join(map(str, self.slots))}
+
+
+def _ep_mesh(cfg, pctx: ParallelContext | None) -> cl.EPMesh:
+    """The expert-parallel slice of the mesh the experts are placed on:
+    (pod, data) where the arch has enough experts, else data alone."""
+    if pctx is None:
+        return cl.EPMesh(pod_axis=None, ep_axis="_none", num_pods=1,
+                         ep_per_pod=1)
+    use_pod, _ = pctx.ep_ranks(cfg.num_experts)
+    return cl.EPMesh(pod_axis=pctx.pod_axis if use_pod else None,
+                     ep_axis=pctx.data_axis,
+                     num_pods=pctx.num_pods if use_pod else 1,
+                     ep_per_pod=pctx.data_size)
 
 
 def plan_moe(cfg, pctx: ParallelContext | None, n_tokens: int, d: int,
              f: int, itemsize: int) -> MoEPlan:
     """The plan :func:`moe_ffn` runs for ``n_tokens`` rows of width ``d``
     (activation ``itemsize`` bytes) through experts of hidden width
-    ``f``.
+    ``f``, at the headroom ``cfg.moe_capacity``.
 
     The WHOLE round trip — dispatch scheme, return-path scheme and the
     shared pipeline chunk count G — is one jointly-planned decision: a
@@ -167,9 +187,15 @@ def plan_moe(cfg, pctx: ParallelContext | None, n_tokens: int, d: int,
     shared-pipeline scorer picks the G where overlapping
     dispatch/compute/combine chunks beats BOTH halves' per-chunk launch
     alphas); the declared moe_scheme/moe_combine/moe_microbatch knobs
-    apply under "fixed"."""
+    apply under "fixed".
+
+    The capacities are sized for the rows per rank, which padding does
+    not raise past the real rows of the fullest rank (padded rows take
+    no slot), and the slots are those of one chunk of them."""
+    epm = _ep_mesh(cfg, pctx)
     if pctx is None:
-        return MoEPlan("hierarchical", "hierarchical", 1, 0, n_tokens)
+        return _chunk_capacities(cfg, epm, "hierarchical", "hierarchical",
+                                 1, 0, n_tokens)
     ranks = pctx.num_pods * pctx.data_size
     pad = -n_tokens % ranks
     n_local = (n_tokens + pad) // ranks
@@ -188,8 +214,22 @@ def plan_moe(cfg, pctx: ParallelContext | None, n_tokens: int, d: int,
     if microbatch != int(pipe_kw["microbatch"]):
         pipe_kw = pctx.moe_pipeline_kwargs(cfg.num_experts, cfg.top_k,
                                            microbatch=microbatch, **kw)
-    return MoEPlan(pipe_kw["moe_scheme"], pipe_kw["moe_combine"],
-                   microbatch, pad, n_local)
+    return _chunk_capacities(cfg, epm, pipe_kw["moe_scheme"],
+                             pipe_kw["moe_combine"], microbatch, pad, n_local)
+
+
+def _chunk_capacities(cfg, epm: cl.EPMesh, dispatch: str, combine: str,
+                      g: int, pad: int, n_local: int) -> MoEPlan:
+    """The plan with its capacities: the balanced load of ``n_local``
+    rows a rank at headroom ``cfg.moe_capacity``, capped at what can
+    arrive in one chunk of ``n_local // g`` of them."""
+    unicast = dispatch == "baseline"
+    headroom = balanced_capacities(
+        n_local, cfg.top_k, epm.num_pods, epm.ep_per_pod,
+        cfg.num_experts // epm.num_ranks, cfg.moe_capacity, unicast)
+    return MoEPlan(dispatch, combine, g, pad, n_local,
+                   cl.capped_capacities(headroom, epm, n_local // g, unicast),
+                   epm)
 
 
 def _placed(state) -> jax.Array:
@@ -197,8 +237,7 @@ def _placed(state) -> jax.Array:
     return jnp.sum(state.map_exp >= 0, dtype=jnp.int32)
 
 
-def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
-            capacity_factor: float | None = None):
+def moe_ffn(params, x, cfg, pctx: ParallelContext | None):
     """x: [B, S, D] -> ([B, S, D], aux_loss, dropped).  params from
     init_moe.  ``dropped`` [R] int32, one count per data-parallel rank
     (R = 1 without a mesh), adds up to the (token, expert) pairs of the
@@ -206,39 +245,20 @@ def moe_ffn(params, x, cfg, pctx: ParallelContext | None,
     summed where it is read, so that counting adds no collective."""
     b, s, d = x.shape
     dt = x.dtype
-    if capacity_factor is None:
-        capacity_factor = cfg.moe_capacity
     n = b * s
     tokens_in = x.reshape(n, d)
+    plan = plan_moe(cfg, pctx, n, d, params["w1"].shape[-1],
+                    x.dtype.itemsize)
+    dcfg, epmesh = plan.dcfg, plan.mesh
 
     if pctx is None:
-        epmesh = cl.EPMesh(pod_axis=None, ep_axis="_none", num_pods=1,
-                           ep_per_pod=1)
-        dcfg = balanced_capacities(n, cfg.top_k, 1, 1, cfg.num_experts,
-                                   capacity_factor)
         out, aux, dropped = _moe_local(params, tokens_in, cfg, dcfg, epmesh)
         return out.reshape(b, s, d).astype(dt), aux, dropped
 
-    use_pod, _ = pctx.ep_ranks(cfg.num_experts)
-    p = pctx.num_pods if use_pod else 1
-    dd = pctx.data_size
-    epmesh = cl.EPMesh(
-        pod_axis=pctx.pod_axis if use_pod else None,
-        ep_axis=pctx.data_axis, num_pods=p, ep_per_pod=dd)
-    per_rank = cfg.num_experts // (p * dd)
-    ep_spec = ((pctx.pod_axis, pctx.data_axis) if use_pod
+    ep_spec = ((epmesh.pod_axis, pctx.data_axis) if epmesh.pod_axis
                else (pctx.data_axis,))
     dp_spec = pctx.dp_axes
-    plan = plan_moe(cfg, pctx, n, d, params["w1"].shape[-1],
-                    x.dtype.itemsize)
     n_local = plan.n_local
-    # capacities follow the rows per rank, which padding does not raise
-    # past the real rows of the fullest rank: padded rows take no slot
-    dcfg = balanced_capacities(n_local, cfg.top_k, p, dd, per_rank,
-                               capacity_factor)
-    if plan.dispatch == "baseline":
-        dcfg = unicast_capacities(dcfg, n_local, cfg.top_k, p * dd,
-                                  per_rank, capacity_factor)
     # rows that do not divide over the data-parallel ranks are padded to
     # the next multiple and masked out of the dispatch; where they divide
     # (decided here, on static shapes) the program has no pad, mask or

@@ -149,3 +149,122 @@ class TestSingleChipDispatch:
         base, mw = cl.dispatch_pod_bytes(ids, cfg, mesh, h=128)
         assert mw < base
         assert base / mw > 2.0  # expected ~4 distinct remote ranks vs ~1 pod
+
+
+# ---------------------------------------------------------------------------
+# stage sizing: headroom x balanced load, capped at what can arrive
+# ---------------------------------------------------------------------------
+
+SIZING_SHAPES = [(n, k, p, d, per_rank)
+                 for n in (1, 2, 7, 64, 256, 2048) for k in (1, 2, 4, 8)
+                 for p in (1, 2, 4) for d in (1, 2, 4) for per_rank in (1, 4)]
+
+
+def _capped(n, rows, k, p, d, per_rank, cf, unicast):
+    """Each stage's slots for a chunk of ``rows`` of a rank's ``n`` rows,
+    (Cp, Cd, Ce) or (Cr, Ce), as :func:`repro.models.moe.plan_moe` sizes
+    them."""
+    from repro.models import moe
+    mesh = cl.EPMesh("pod" if p > 1 else None, "ep", p, d)
+    headroom = moe.balanced_capacities(n, k, p, d, per_rank, cf, unicast)
+    return cl.dispatch_slots(cl.capped_capacities(headroom, mesh, rows,
+                                                  unicast),
+                             mesh, rows, unicast)
+
+
+def _uncapped(n, rows, k, p, d, per_rank, cf, unicast):
+    """The slots the sizing gave before any stage was capped: headroom
+    ``cf`` times the balanced load, set at ``n`` rows and applied to a
+    chunk of ``rows`` with ``max(1, round(...))`` at each stage."""
+    def slots(x):
+        return max(1, int(round(x)))
+    ce_target = slots(n * k / per_rank * cf)
+    if unicast:
+        rank_cap = min(1.0, k / (p * d)) * cf
+        exp_cap = ce_target / (p * d * slots(n * rank_cap))
+        cr = slots(rows * rank_cap)
+        return cr, slots(p * d * cr * exp_cap)
+    pod_cap = min(1.0, k / p) * cf
+    ep_cap = min(1.0, (k / p) / d) * cf
+    exp_cap = ce_target / (d * slots(p * slots(n * pod_cap) * ep_cap))
+    cp = slots(rows * pod_cap)
+    cd = slots(p * cp * ep_cap)
+    return cp, cd, slots(d * cd * exp_cap)
+
+
+@pytest.mark.parametrize("unicast", [False, True], ids=["multiwrite",
+                                                         "unicast"])
+@pytest.mark.parametrize("cf", [0.25, 1.25, 4.0, 8.0])
+def test_stage_slots_are_headroom_capped_at_the_worst_case(cf, unicast):
+    """Each stage allocates min(headroom x balanced load, most rows that
+    can arrive there): stage 1 at most the chunk's rows (one copy per
+    token and pod), stage 2 at most P*Cp (one per stage-1 slot and ep
+    rank), unicast at most the rows per rank, an expert at most every
+    row that reaches its rank.  Wherever the headroom's size is within
+    that worst case it is kept, in whole launches and in chunks."""
+    for n, k, p, d, per_rank in SIZING_SHAPES:
+        for g in (1, 2, 8):
+            if n % g:
+                continue
+            rows = n // g
+            got = _capped(n, rows, k, p, d, per_rank, cf, unicast)
+            want = _uncapped(n, rows, k, p, d, per_rank, cf, unicast)
+            fans = (1, p * d) if unicast else (1, p, d)
+            worst = rows
+            for stage, (slots, wish, fan) in enumerate(zip(got, want, fans)):
+                worst *= fan
+                assert slots == min(wish, worst), (n, g, k, p, d, per_rank,
+                                                   stage, got, want)
+                worst = slots
+
+
+@pytest.mark.parametrize("shape,want", [
+    # 2 pods x 4 ranks, top 2: stage 1 wants 80 of 64 rows, and stage 2
+    # keeps its 50 slots of the 128 that can arrive
+    ((64, 64, 2, 2, 4, 1, 1.25, False), (64, 50, 160)),
+    # top 8 of 384 experts over 2 x 8 ranks: stage 2 keeps 400 of 512
+    ((256, 256, 8, 2, 8, 24, 1.25, False), (256, 400, 107)),
+    # the headroom within every worst case: nothing changes
+    ((256, 256, 4, 2, 2, 4, 0.25, False), (64, 32, 64)),
+    ((256, 256, 4, 2, 2, 4, 0.25, True), (64, 64)),
+])
+def test_slots_keep_the_headroom_within_the_worst_case(shape, want):
+    """Where headroom times the balanced load is within a stage's worst
+    case, the stage keeps those slots: a capped stage before it does
+    not shrink it."""
+    assert _capped(*shape) == want
+
+
+@pytest.mark.parametrize("launch,rows,g,want", [
+    ("prefill", 2048, 8, (256, 512, 1024)),
+    ("decode", 2, 1, (2, 8)),
+])
+def test_ep4_cell_slots(launch, rows, g, want):
+    """DBRX on the 2x2 mesh at capacity 4.0 (16 experts top 4, 4 a
+    chip): a prefill chunk of 256 rows a chip under MultiWrite G = 8, a
+    decode of 2 rows a chip under unicast.  Stage 1, stage 2 and the
+    unicast buffer hold what can arrive; the expert slots, 1024 and 8,
+    are the no-drop worst case and the headroom's, as before the cap."""
+    unicast = launch == "decode"
+    assert _capped(rows, rows // g, 4, 2, 2, 4, 4.0, unicast) == want
+    assert _uncapped(rows, rows // g, 4, 2, 2, 4, 4.0, unicast)[-1] == want[-1]
+
+
+@pytest.mark.parametrize("dispatch,field", [
+    ("hierarchical", "pod_capacity"), ("hierarchical", "ep_capacity"),
+    ("hierarchical", "expert_capacity"), ("baseline", "pod_capacity"),
+    ("baseline", "expert_capacity")])
+def test_dispatch_refuses_slots_that_cannot_fill(dispatch, field):
+    """A share above 1 of a stage's worst case is refused at trace time,
+    before any slot is allocated."""
+    import dataclasses
+    mesh = cl.EPMesh(pod_axis=None, ep_axis="ep", num_pods=1, ep_per_pod=1)
+    cfg = dataclasses.replace(cl.DispatchConfig(num_experts=8, top_k=2),
+                              **{field: 1.5})
+    rng = np.random.default_rng(2)
+    tokens = jnp.asarray(rng.normal(size=(8, 4)).astype(np.float32))
+    gates, ids = cl.route_topk(
+        jnp.asarray(rng.normal(size=(8, 8)).astype(np.float32)), 2)
+    fn = getattr(cl, f"{dispatch}_dispatch")
+    with pytest.raises(ValueError, match="can arrive"):
+        fn(tokens, ids, gates, cfg, mesh)

@@ -139,6 +139,26 @@ for scheme, g in (("hierarchical", 1), ("hierarchical", 2),
                   moe.plan_moe(cfg, pctx, 8 * S, 64, 64, 4).attrs()]}}
 out["moe_ffn"] = pipe
 
+# the worst routing the cap must survive: every token picks experts 0-3,
+# all on the rank at (pod 0, data 0), so every stage fills to its
+# no-drop worst case; the one-device layer is the comparison
+bias = jnp.zeros((64, 16)).at[0, :4].set(100.0)
+forced = dict(layer, router=0.01 * layer["router"] + bias)
+xf = x.at[..., 0].set(1.0)
+alone, _, dropped1 = moe.moe_ffn(forced, xf, cfg, None)
+worst = {{"one_device_dropped": int(np.sum(dropped1))}}
+for scheme in ("hierarchical", "baseline"):
+    pctx = make_pctx(fsdp=False, plan_policy="fixed", moe_scheme=scheme)
+    ids = jax.lax.top_k(xf.reshape(-1, 64) @ forced["router"], 4)[1]
+    got, _, dropped = jax.jit(
+        lambda x: moe.moe_ffn(forced, x, cfg, pctx))(xf)
+    worst[scheme] = {{
+        "experts": sorted({{int(e) for e in np.asarray(ids).ravel()}}),
+        "error": float(jnp.abs(got - alone).max()),
+        "dropped": int(np.sum(dropped)),
+        "slots": moe.plan_moe(cfg, pctx, 8 * S, 64, 64, 4).attrs()}}
+out["worst_case"] = worst
+
 # the dry run's out_shardings take every family's (logits, cache, stats)
 # on the mesh: a dense stack's empty stats and the MoE drop counts
 from repro.launch.dryrun import serve_out_shardings
@@ -193,13 +213,22 @@ def test_cohort_matches_the_reference(served, case):
 @pytest.mark.parametrize("case", CASES)
 def test_launch_records_its_plan_and_drops(served, case):
     """Each prefill and decode span carries the rows padded on, the
-    plan its layers ran, and no dropped pair at capacity 4.0."""
+    plan its layers ran, the slots its dispatch stages allocate, and no
+    dropped pair at capacity 4.0."""
     b = int(case.rsplit("-", 1)[1])
     launches = served["launches"][case]
     assert [a["ep_pad_rows"] for a in launches] == \
         [-b * PROMPT_LEN % 4] + [-b % 4] * (len(launches) - 1)
     for a in launches:
         assert a["moe_dropped"] == 0
+        # 16 experts top 4 over 2 pods x 2 ranks at capacity 4.0: every
+        # stage sits at the most rows that can arrive in the launch's
+        # chunk of c rows a chip (unicast: c a rank, 4c an expert)
+        rows = b * PROMPT_LEN if a is launches[0] else b
+        c = (rows + a["ep_pad_rows"]) // 4 // a["moe_g"]
+        assert a["moe_slots"] == (f"{c}/{2 * c}/{4 * c}"
+                                  if a["moe_dispatch"] == "hierarchical"
+                                  else f"{c}/{4 * c}")
         assert a["moe_dispatch"] in ("hierarchical", "baseline")
         assert a["moe_combine"] in ("hierarchical", "baseline")
         assert a["moe_g"] >= 1
@@ -230,6 +259,22 @@ def test_padding_only_where_rows_do_not_divide(served, case):
     assert row["pads"][0] > 0 and row["pads"][1] == 0
     assert [p["ep_pad_rows"] for p in row["plans"]] == [3, 0]
     assert [p["moe_g"] for p in row["plans"]] == [int(case[-1])] * 2
+
+
+@pytest.mark.parametrize("scheme", ["hierarchical", "baseline"])
+def test_worst_case_routing_drops_nothing(served, scheme):
+    """A router planted so that every token picks the same 4 experts,
+    all on one rank, fills each stage to the most rows that can arrive:
+    at capacity 4.0 the slots sized to that worst case hold them all,
+    and the rows match the one-device layer's."""
+    worst = served["worst_case"]
+    row = worst[scheme]
+    assert row["experts"] == [0, 1, 2, 3]
+    assert row["dropped"] == 0 and worst["one_device_dropped"] == 0
+    assert row["error"] < TOLERANCE
+    assert row["slots"]["moe_dispatch"] == scheme
+    assert row["slots"]["moe_slots"] == ("14/28/56" if scheme ==
+                                         "hierarchical" else "14/56")
 
 
 @pytest.mark.parametrize("case", ["dbrx-prefill", "dbrx-decode",
