@@ -1,7 +1,8 @@
 """Paper-table/figure reproductions (one function per table/figure).
 
 Each function returns a list of row-dicts and prints a compact table;
-benchmarks.run drives them all and emits CSV.  Sources:
+``ALL`` names them all, and tests/test_benchmarks.py checks each.
+Sources:
 
   fig2_section31   §3.1 derivation table (exact, zero-overhead regime)
   fig6_allgather   AllGather latency at 16 MB: baseline vs unicast

@@ -60,9 +60,8 @@ class NoFeasiblePlanError(RuntimeError):
     """Every candidate of an op was masked as infeasible under the
     topology's :class:`~repro.core.topology.FailureState` — the fabric is
     effectively partitioned for this collective.  Raised instead of
-    scoring garbage on links that cannot carry traffic; callers (serving
-    tier, stress harness) treat it as "shed or hold traffic", never as a
-    plan."""
+    scoring garbage on links that cannot carry traffic; callers (the
+    serving tier) treat it as "shed or hold traffic", never as a plan."""
 
     def __init__(self, op: str, fabric: str, masked: list[str]):
         self.op = op
@@ -100,11 +99,10 @@ def ledger_infeasible(ledger, failures) -> Optional[str]:
 def plan_site_ledgers(eplan, topo: Topology) -> dict:
     """Re-simulate each site decision of ``eplan`` on ``topo`` and
     return ``role -> Ledger`` — the byte ledgers the bound plan actually
-    executes.  This is the post-hoc feasibility audit surface: the
-    stress harness asserts that no ledger of a serving plan charges a
-    link the hidden ground truth has killed (the "never execute an
-    infeasible plan" invariant, checked against TRUTH rather than
-    against the detector's belief)."""
+    executes.  This is the post-hoc feasibility audit surface: no ledger
+    of a retargeted plan may charge a link the ground truth has killed
+    (the "never execute an infeasible plan" invariant, checked in
+    tests/test_failover.py)."""
     out = {}
     for role in sorted(eplan.decisions):
         site = next((s for s in eplan.program.sites if s.role == role),
@@ -1075,8 +1073,9 @@ def moe_dispatch_decision(*, num_pods: int, ep_per_pod: int,
                           skew: float = 0.0,
                           compute_s: float = 0.0) -> PlanDecision:
     """Plan the MoE dispatch for one EP mesh slice INDEPENDENTLY of its
-    return path — the dispatch-first reference (what-if reports and
-    ``bench_program``'s comparison baseline); executing consumers plan
+    return path — the dispatch-first reference (what-if reports and the
+    baseline tests/test_program.py compares joint planning against);
+    executing consumers plan
     the (dispatch, combine) pair jointly via :meth:`Planner.plan_program`
     (see :func:`_ep_topology` for the fabric the payload is scored on).
     The payload is the per-rank token traffic of one dispatch.

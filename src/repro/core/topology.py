@@ -635,7 +635,7 @@ def same_index_peer(topo: Topology, node: int, dst_server: int) -> int:
     return topo.same_index_peer(node, dst_server)
 
 
-# -- registered fabrics (planner scenarios; bench_fabrics sweeps these) -----
+# -- registered fabrics (planner scenarios; tests/test_fabrics.py sweeps) ---
 register_fabric("mesh8", lambda: full_mesh(8))
 register_fabric("2x8", lambda: two_server_cluster())
 register_fabric("4x8", lambda: ClusterSpec(num_servers=4, name="4x8").build())

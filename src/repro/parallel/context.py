@@ -17,18 +17,10 @@ Axis roles on the production mesh (launch/mesh.py):
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def _deprecated(name: str, repl: str) -> None:
-    warnings.warn(
-        f"ParallelContext.{name} is deprecated (one release): collective "
-        f"sites are planned as a whole program now — use {repl}",
-        DeprecationWarning, stacklevel=3)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -351,94 +343,6 @@ class ParallelContext:
             "allgather", float(frag_bytes), topo, executable_only=True,
             num_domains=num_domains)
 
-    # -- deprecated per-op resolution shims (one release) ---------------------
-    # The resolve_*/moe_*_plan knob zoo planned every site independently;
-    # coupled sites are planned jointly through CollectiveProgram /
-    # ExecutionPlan now.  These delegate to the program path so legacy
-    # callers see the jointly-planned answers.
-    def _moe_site_decision(self, op: str, num_experts: int, top_k: int,
-                           tokens_per_rank: int, token_bytes: int,
-                           compute_s: float = 0.0):
-        payload = float(tokens_per_rank) * token_bytes
-        scen = dict(num_experts=num_experts, top_k=top_k,
-                    token_bytes=token_bytes)
-        if self.execution_plan is not None:
-            role = self.execution_plan.find_role(
-                op, payload, skew=self.moe_skew, compute_s=compute_s,
-                **scen)
-            if role is not None:
-                return self.execution_plan.decision(role)
-        if self.plan_policy != "auto":
-            return None
-        from repro.core import plan as plan_ir
-        sites = self.moe_sites("auto", num_experts=num_experts,
-                               top_k=top_k, tokens_per_rank=tokens_per_rank,
-                               token_bytes=token_bytes, compute_s=compute_s)
-        eplan = self.plan_collectives(
-            plan_ir.CollectiveProgram("moe/auto", sites))
-        role = sites[0].role if op == "dispatch" else sites[1].role
-        return eplan.decision(role)
-
-    def moe_dispatch_plan(self, num_experts: int, top_k: int,
-                          tokens_per_rank: int, token_bytes: int,
-                          compute_s: float = 0.0):
-        """DEPRECATED shim: the per-site dispatch view of the jointly
-        planned MoE pipeline (``None`` under "fixed" with no bound
-        plan).  Use ``plan_collectives`` + ``ExecutionPlan.decision``."""
-        _deprecated("moe_dispatch_plan",
-                    "plan_collectives(program).decision(role)")
-        return self._moe_site_decision("dispatch", num_experts, top_k,
-                                       tokens_per_rank, token_bytes,
-                                       compute_s)
-
-    def moe_combine_plan(self, num_experts: int, top_k: int,
-                         tokens_per_rank: int, token_bytes: int,
-                         compute_s: float = 0.0):
-        """DEPRECATED shim: the per-site combine view of the jointly
-        planned MoE pipeline (no longer planned independently of
-        dispatch — the executable-pairing constraint and the shared
-        microbatch G apply)."""
-        _deprecated("moe_combine_plan",
-                    "plan_collectives(program).decision(role)")
-        return self._moe_site_decision("combine", num_experts, top_k,
-                                       tokens_per_rank, token_bytes,
-                                       compute_s)
-
-    def resolve_moe_dispatch(self, num_experts: int, top_k: int,
-                             tokens_per_rank: int, token_bytes: int,
-                             compute_s: float = 0.0) -> dict:
-        """DEPRECATED shim: ``{"moe_scheme", "microbatch"}`` of the
-        jointly planned pipeline.  Use :meth:`moe_pipeline_kwargs`."""
-        _deprecated("resolve_moe_dispatch", "moe_pipeline_kwargs")
-        kw = self.moe_pipeline_kwargs(num_experts, top_k, tokens_per_rank,
-                                      token_bytes, compute_s=compute_s)
-        return {"moe_scheme": kw["moe_scheme"],
-                "microbatch": kw["microbatch"]}
-
-    def resolve_moe_scheme(self, num_experts: int, top_k: int,
-                           tokens_per_rank: int, token_bytes: int,
-                           compute_s: float = 0.0) -> str:
-        """DEPRECATED shim: the dispatch scheme of the jointly planned
-        pipeline.  Use :meth:`moe_pipeline_kwargs`."""
-        _deprecated("resolve_moe_scheme", "moe_pipeline_kwargs")
-        return self.moe_pipeline_kwargs(
-            num_experts, top_k, tokens_per_rank, token_bytes,
-            compute_s=compute_s)["moe_scheme"]
-
-    def resolve_combine_scheme(self, num_experts: int, top_k: int,
-                               tokens_per_rank: int, token_bytes: int,
-                               compute_s: float = 0.0,
-                               microbatch: Optional[int] = None) -> str:
-        """DEPRECATED shim: the return-path scheme of the jointly
-        planned pipeline.  ``microbatch`` is accepted for compatibility
-        and ignored — the joint sweep already chooses the combine scheme
-        at the ONE shared G the pipeline executes."""
-        _deprecated("resolve_combine_scheme", "moe_pipeline_kwargs")
-        del microbatch
-        return self.moe_pipeline_kwargs(
-            num_experts, top_k, tokens_per_rank, token_bytes,
-            compute_s=compute_s)["moe_combine"]
-
 
 def build_collective_program(cfg, pctx: ParallelContext, name: str,
                              phases: dict, *, itemsize: int = 2,
@@ -512,8 +416,8 @@ class PlanBinder:
       (or found in the cache) at stage time, OFF the step path.
 
     :meth:`swap_if_pending` is called at step boundaries and is a pure
-    pointer swap when the staged lowering is cached (the invariant the
-    stress soak asserts: zero cold retraces).  A swap whose artifact is
+    pointer swap when the staged lowering is cached (zero cold retraces,
+    asserted in tests/test_failover.py).  A swap whose artifact is
     missing — evicted, or staged around the cache — builds it AT the
     swap point and counts it as a cold retrace, so regressions are
     observable rather than silent.  Re-binding to a previously-seen
@@ -589,23 +493,6 @@ class PlanBinder:
             return False
         self._build(plan)
         self._pending = plan
-        return True
-
-    def prefetch(self, plan) -> bool:
-        """Warm the traced-lowering cache for ``plan`` WITHOUT staging a
-        swap — the serving tier's batch-bucket prefetch.  The
-        neighboring bucket's lowering is built here, off the step path,
-        so a later :meth:`stage` + :meth:`swap_if_pending` when the
-        decode batch grows across the bucket boundary is a pure pointer
-        flip (mirroring the failover swap).  Returns True when this
-        call built the artifact; False when it was already cached (or
-        already active)."""
-        key = self._key(plan)
-        if key == self._key(self._active[0]) or key in self._cache:
-            return False
-        self._build(plan)
-        self._metrics()["repro_plan_prefetch_total"].inc(
-            program=self._program(plan))
         return True
 
     def swap_if_pending(self) -> bool:

@@ -242,16 +242,6 @@ class ServeEngine:
         from repro.core.plan import batch_bucket
         return self._fresh_plan(batch_bucket(max(1, batch)), prompt_len)
 
-    def prefetch_bucket(self, batch: int, prompt_len: int) -> bool:
-        """Warm the traced-lowering cache for the bucketed serving
-        shape's plan, off the step path (``PlanBinder.prefetch``), so a
-        later admission across the bucket boundary swaps on a pointer
-        flip.  Returns True when a lowering was built."""
-        plan = self.bucket_plan(batch, prompt_len)
-        if plan is None:
-            return False
-        return self._binder.prefetch(plan)
-
     def plan_probe(self, itemsize: int = 2):
         """PlannerProbe over this engine's fabric/calibration — the
         admission controller's latency oracle.  ``itemsize`` must match

@@ -1,4 +1,4 @@
-"""Continuous-batching serving tier (ISSUE 10).
+"""Continuous-batching serving tier.
 
 The traffic side of the millions-of-users path: an open-loop request
 queue, an iteration-level :class:`BatchScheduler` (finished sequences
@@ -7,12 +7,13 @@ barrier), and a *planner-informed* :class:`AdmissionController` that
 consults the bound ExecutionPlan's batch-dependent scheme crossovers
 and phase budgets before growing the decode batch, staging the next
 batch bucket's plan through ``PlanBinder`` ahead of admission so batch
-growth is a pointer flip (mirroring the PR 9 failover swap).
+growth is a pointer flip (mirroring the failover swap).
 
 Dataflow: queue -> admit -> schedule -> bind (see ARCHITECTURE.md).
-Everything here is numpy-only and virtual-time (no wall clock), so the
-whole tier is simulation-testable on CPU like SimProbe; plugging in a
-``ServeEngine`` makes the same scheduler drive real prefill/decode.
+Everything here is numpy-only; with a ``PlannerProbe`` and no engine
+the scheduler runs on a virtual clock, so the whole tier is
+simulation-testable on CPU like SimProbe.  Plugging in a ``ServeEngine``
+makes the same scheduler drive real prefill/decode.
 """
 
 from repro.serving.admission import (AdmissionController, AdmissionDecision,

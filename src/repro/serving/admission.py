@@ -23,10 +23,9 @@ scheme crossovers reproduced since PR 1):
   join: the swap at the next step boundary is a pointer flip, never a
   cold retrace.
 
-A ``policy="greedy"`` controller is the crossover-oblivious baseline
-``bench_serving`` compares against: it admits everything and never
-stages a re-bind, so a grown batch keeps executing the scheme that won
-at the small bucket.
+A ``policy="greedy"`` controller is the crossover-oblivious baseline:
+it admits everything and never stages a re-bind, so a grown batch keeps
+executing the scheme that won at the small bucket.
 """
 
 from __future__ import annotations

@@ -13,8 +13,7 @@ requests whose ``arrival_s`` has passed are visible, and within the
 visible set the deadline classes pop in priority order
 (``interactive`` before ``standard`` before ``batch``), FIFO inside a
 class.  The queue never drops — backpressure is the admission
-controller's job, and the stress soak asserts a dark rail drains the
-queue without losing a request.
+controller's job.
 """
 
 from __future__ import annotations

@@ -6,9 +6,7 @@ bucket plan was prefetched), (2) consult the admission controller and
 prefill the joining requests as a new *cohort*, (3) run one decode
 round over every in-flight cohort.  Finished sequences release their
 admission capacity at the iteration boundary and new requests join
-right behind them — there is no drain-the-batch barrier
-(``static_batching=True`` restores the barrier as the benchmark
-baseline: nothing is admitted while any cohort is in flight).
+right behind them — there is no drain-the-batch barrier.
 
 A **cohort** is the set of requests admitted together: one prefill
 call, position-aligned thereafter (every row advances one token per
@@ -25,7 +23,7 @@ times from a :class:`~repro.serving.admission.PlannerProbe` (falling
 back to measured wall when an engine runs without a probe), so the
 whole tier is deterministic and CPU-simulation-testable.  With
 ``engine=None`` no tokens are computed at all — pure scheduling
-simulation, what ``bench_serving`` sweeps and the stress soak drives.
+simulation, what tests/test_serving.py drives.
 While the span recorder (:mod:`repro.telemetry.spans`) is on, each
 :meth:`step` is also timed on the wall clock: ``sched.iteration`` around
 it, ``sched.admit``, ``sched.decode_round`` and ``sched.finalize``
@@ -90,7 +88,6 @@ class BatchScheduler:
                  admission: AdmissionController,
                  engine=None, probe=None, binder=None,
                  plan_for_bucket: Optional[Callable] = None,
-                 static_batching: bool = False,
                  eos_id: Optional[int] = None, seed: int = 0,
                  max_iterations: int = 1_000_000) -> None:
         self.queue = queue
@@ -99,12 +96,10 @@ class BatchScheduler:
         self.probe = probe
         self.binder = binder
         self.plan_for_bucket = plan_for_bucket
-        self.static_batching = static_batching
         self.eos_id = eos_id
         self.seed = seed
         self.max_iterations = max_iterations
         self.now = 0.0
-        self.step_time_scale = 1.0      # soak harness: degraded-fabric stall
         self.cohorts: List[_Cohort] = []
         self.completed: List[Request] = []
         self.iterations = 0
@@ -157,8 +152,7 @@ class BatchScheduler:
         # (2) admission
         joiners: List[Request] = []
         ready = self.queue.ready_count(self.now)
-        barrier = self.static_batching and bool(self.cohorts)
-        if ready and not barrier:
+        if ready:
             with spans.span("sched.admit") as sp:
                 dec = self.admission.decide(
                     in_flight=self.in_flight, ready=ready,
@@ -205,9 +199,8 @@ class BatchScheduler:
                 r.predicted_ttft_s = self.probe.prefill_s(n, prompt_len)
                 r.predicted_tpot_s = self.probe.decode_step_s(
                     in_flight_after)
-        if self.bound_bucket is None or self.static_batching:
-            # first admission (or a fresh static batch): the plan bound
-            # at startup covers this bucket
+        if self.bound_bucket is None:
+            # first admission: the plan bound at startup covers this bucket
             self.bound_bucket = batch_bucket(max(1, in_flight_after))
         cohort = _Cohort(requests=joiners)
         dt = 0.0
@@ -223,7 +216,7 @@ class BatchScheduler:
             if self.probe is None:
                 dt = wall
         if self.probe is not None:
-            dt = self.probe.prefill_s(n, prompt_len) * self.step_time_scale
+            dt = self.probe.prefill_s(n, prompt_len)
         self._emit(cohort, cohort.pending)
         self.cohorts.append(cohort)
         _metrics()["repro_requests_total"].inc(n, outcome="admitted")
@@ -254,8 +247,7 @@ class BatchScheduler:
         if self.probe is not None:
             if total > 0:
                 dt = self.probe.decode_step_s(
-                    total, bound_batch=self.bound_bucket) * \
-                    self.step_time_scale
+                    total, bound_batch=self.bound_bucket)
         return dt
 
     def _emit(self, cohort: _Cohort, tokens) -> None:
@@ -315,18 +307,6 @@ class BatchScheduler:
                 return self
         raise RuntimeError(f"scheduler did not drain within "
                            f"{self.max_iterations} iterations")
-
-    def run_for(self, duration_s: float) -> "BatchScheduler":
-        """Advance the virtual clock by ``duration_s`` (the soak
-        harness's per-epoch window); returns early when fully idle."""
-        t_end = self.now + duration_s
-        for _ in range(self.max_iterations):
-            if self.now >= t_end:
-                return self
-            if not self.step():
-                self.now = t_end
-                return self
-        raise RuntimeError("run_for exceeded max_iterations")
 
     # -- reporting -----------------------------------------------------------
     def report(self, *, ttft_slo_s: Optional[float] = None,

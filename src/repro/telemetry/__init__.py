@@ -19,8 +19,7 @@ planner's feedback loop.
                 the profiler's clock; off unless spans.enable() is called
 
 Consumed by: ParallelContext(calibration=...), train.py/serve.py
---calibrate, dryrun --calibration, ServeEngine.plan_report,
-launch/stress.py soak runs and benchmarks bench_calibration.
+--calibrate, dryrun --calibration and ServeEngine.plan_report.
 """
 
 from .exporter import MetricsExporter, scrape, write_snapshot

@@ -11,9 +11,7 @@ Two delivery modes over the same rendered registry:
                                          CI runs diff
 
 No third-party dependencies: the scrape path must never be the thing
-that takes the server down, and the stress harness scrapes its own
-in-process exporter over real HTTP each epoch (the same bytes an
-operator's Prometheus would pull).
+that takes the server down.
 """
 
 from __future__ import annotations
@@ -114,14 +112,13 @@ def write_snapshot(path: str,
 
 
 def scrape(url: str, timeout: float = 5.0) -> str:
-    """HTTP-GET a /metrics URL and return the body text (the stress
-    harness's curl-equivalent)."""
+    """HTTP-GET a /metrics URL and return the body text."""
     from urllib.request import urlopen
     with urlopen(url, timeout=timeout) as resp:
         return resp.read().decode("utf-8")
 
 
-# -- launcher plumbing (train.py / serve.py / dryrun / stress share it) -----
+# -- launcher plumbing (train.py / serve.py / dryrun share it) --------------
 
 def add_metrics_args(parser) -> None:
     parser.add_argument("--metrics-port", type=int, default=None,

@@ -274,7 +274,7 @@ class MetricsRegistry:
 
 
 # ---------------------------------------------------------------------------
-# text-format parsing (tests + the stress harness's scrape assertions)
+# text-format parsing (scrape assertions in tests)
 # ---------------------------------------------------------------------------
 
 def parse_text(text: str) -> dict:
@@ -551,8 +551,8 @@ def default_registry() -> MetricsRegistry:
 
 
 def reset_default_registry() -> MetricsRegistry:
-    """Zero every series of the process-wide registry (tests / the
-    stress harness start each run from a clean plane)."""
+    """Zero every series of the process-wide registry (tests start
+    from a clean plane)."""
     reg = default_registry()
     reg.reset()
     return reg

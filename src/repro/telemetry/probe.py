@@ -203,8 +203,8 @@ class GroundTruth:
     def degraded(self, topo: Topology, factor: float,
                  which: str = "inter") -> "GroundTruth":
         """Truth with every ``which``-class link of ``topo`` delivering
-        ``factor``x less bandwidth than it currently does — the long-term
-        stress-test scenario (§6: deployed links drift off datasheet)."""
+        ``factor``x less bandwidth than it currently does — the paper's
+        long-term drift scenario (§6: deployed links drift off datasheet)."""
         cur = dict(self.link_bw)
         links = {}
         for key, ln in topo.links.items():
@@ -214,7 +214,7 @@ class GroundTruth:
 
     def with_dead(self, links) -> "GroundTruth":
         """Truth with ``links`` (directed ``(src, dst)`` pairs) fully
-        dark — the scripted rail blackout of the failure-events soak."""
+        dark — a rail blackout for the failure detector."""
         dead = set(self.dead_links)
         dead.update((int(a), int(b)) for a, b in links)
         return dataclasses.replace(self, dead_links=tuple(sorted(dead)))

@@ -17,9 +17,8 @@ Boundaries are inclusive on the cheaper side: measured == 1.2x is still
 edge never flaps to the worse class from float formatting).
 
 Consumed by DriftMonitor.observe (every probe record is classified into
-``repro_slo_class_total`` / ``repro_slo_ratio``) and by the stress
-harness, which asserts good -> poor -> good across an injected
-degradation window.
+``repro_slo_class_total`` / ``repro_slo_ratio``); tests/test_telemetry.py
+asserts good -> poor -> good across an injected degradation window.
 """
 
 from __future__ import annotations

@@ -158,11 +158,12 @@ class TestCrossover:
         assert flip(get_fabric("tpu_2x16")) < flip(get_fabric("2x8"))
 
     def test_lossy_scheme_never_auto_bound(self):
-        planner = pl.Planner()
-        for log2 in (16, 20, 24, 28):
-            d = planner.choose("allreduce", float(1 << log2), TOPO,
-                               executable_only=True)
-            assert d.plan != "compressed"
+        for topo in (TOPO, get_fabric("tpu_2x16")):
+            planner = pl.Planner()
+            for log2 in (16, 20, 24, 28):
+                d = planner.choose("allreduce", float(1 << log2), topo,
+                                   executable_only=True)
+                assert d.plan != "compressed", (topo.name, log2)
 
     def test_reduce_scatter_has_a_winner(self):
         d = pl.Planner().choose("reduce_scatter", float(1 << 22), TOPO,

@@ -1,4 +1,4 @@
-"""Tests for the benchmark harness + roofline builder."""
+"""Tests for the paper-figure tables + roofline builder."""
 
 import json
 import os

@@ -244,6 +244,11 @@ class TestPhaseSearch:
         assert stats["combos_scored"] < 0.10 * stats["product"]
         assert stats["combos_pruned"] == (stats["product"]
                                           - stats["combos_scored"])
+        # and lands within 2% of the forced-exhaustive oracle
+        oracle = pl.Planner(search="exhaustive").plan_program(program, topo)
+        beam_s = eplan.phase_report["train"]["score_s"]
+        oracle_s = oracle.phase_report["train"]["score_s"]
+        assert beam_s <= oracle_s * 1.02
 
     def test_zero_contention_reproduces_independent_planning(self):
         """Groups on disjoint fabrics cannot contend: the joint search

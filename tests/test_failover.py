@@ -146,6 +146,13 @@ class TestFeasibilityMasking:
         truth_fs = failed.failures
         for role, led in pl.plan_site_ledgers(eplan, failed).items():
             assert pl.ledger_infeasible(led, truth_fs) is None, role
+        # losing a rail never makes an op predict faster than healthy
+        for op in ("dispatch", "allreduce", "reduce_scatter"):
+            healthy = planner.choose(op, 8 << 20, topo,
+                                     executable_only=True)
+            hurt = planner.choose(op, 8 << 20, failed,
+                                  executable_only=True)
+            assert hurt.predicted_s >= healthy.predicted_s * (1 - 1e-9), op
 
     def test_relay_ladder_multiwrite_to_unicast(self):
         topo = get_fabric("2x8")

@@ -16,10 +16,7 @@ Covers:
     decision-flip counter, and the decision_log ring buffer (the
     unbounded-growth fix) — including that fit_overlap_eff still sees
     its measurement rows after trimming.
-  * Docs-sync: every metric in METRIC_SPECS is documented in METRICS.md
-    (mirrors the grep gate in ci.yml).
-  * Stress soak smoke: the full injected-degradation loop with all five
-    assertions, in-process.
+  * Docs-sync: every metric in METRIC_SPECS is documented in METRICS.md.
 """
 
 import math
@@ -285,7 +282,7 @@ def test_planner_metrics_decisions_cache_and_flips():
 
 
 # ---------------------------------------------------------------------------
-# docs-sync (mirrors the ci.yml grep gate)
+# docs-sync
 
 
 def test_every_metric_documented_in_metrics_md():
@@ -295,18 +292,3 @@ def test_every_metric_documented_in_metrics_md():
         doc = f.read()
     missing = [name for name in m.METRIC_SPECS if name not in doc]
     assert not missing, f"undocumented metrics: {missing}"
-
-
-# ---------------------------------------------------------------------------
-# stress soak (smoke shape, in-process)
-
-
-def test_stress_soak_smoke(tmp_path):
-    from repro.launch.stress import run_soak
-    out = str(tmp_path / "STRESS_soak.json")
-    result = run_soak(epochs=6, smoke=True, out_path=out)
-    assert result["ok"], result["assertions"]
-    assert os.path.exists(out)
-    names = {a["name"] for a in result["assertions"]}
-    assert names == {"detection", "convergence", "flips", "stale", "slo"}
-    assert all(a["ok"] for a in result["assertions"])

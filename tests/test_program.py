@@ -17,8 +17,6 @@ What is pinned here:
     fabric mismatch guard);
   * whole-program replanning after a re-calibration
     (``Planner.replan_programs`` / ``DriftMonitor.recalibrate``);
-  * the deprecated ``resolve_*`` shims: one release of warning +
-    agreement with the new joint path;
   * ``StepAttribution``: live step wall times reach
     ``fit_overlap_eff`` through ``Planner.note_measurement``;
   * the directed linkprobe: never-bottlenecking rail directions get
@@ -26,8 +24,6 @@ What is pinned here:
 """
 
 import dataclasses
-import os
-import re
 
 import pytest
 
@@ -203,6 +199,9 @@ class TestPlanProgram:
         b = planner.plan_program(prog, topo)
         assert a is b                      # LRU hit
         assert a.fingerprint == b.fingerprint
+        # deterministic across planners, not just a cache hit
+        assert pl.Planner().plan_program(prog, topo).fingerprint == \
+            a.fingerprint
         degraded = planner.hw.recalibrated(
             {"links": {k: ln.bw / 4 for k, ln in topo.links.items()
                        if topo.server_of(k[0]) != topo.server_of(k[1])}})
@@ -329,62 +328,6 @@ class TestBinding:
         assert (d.plan, d.knobs) == \
             (eplan.decision("train/split_tp_gather").plan,
              eplan.decision("train/split_tp_gather").knobs)
-
-
-# ---------------------------------------------------------------------------
-# deprecated shims (one release)
-# ---------------------------------------------------------------------------
-
-class TestDeprecatedShims:
-    @pytest.fixture()
-    def pctx(self):
-        return _mesh_pctx(plan_policy="auto", fabric=two_server_cluster())
-
-    def test_shims_warn_and_agree_with_joint_path(self, pctx):
-        kw = pctx.moe_pipeline_kwargs(64, 8, 2048, TOKEN)
-        with pytest.warns(DeprecationWarning, match="resolve_moe_scheme"):
-            assert pctx.resolve_moe_scheme(64, 8, 2048, TOKEN) == \
-                kw["moe_scheme"]
-        with pytest.warns(DeprecationWarning,
-                          match="resolve_combine_scheme"):
-            assert pctx.resolve_combine_scheme(64, 8, 2048, TOKEN) == \
-                kw["moe_combine"]
-        with pytest.warns(DeprecationWarning,
-                          match="resolve_moe_dispatch"):
-            got = pctx.resolve_moe_dispatch(64, 8, 2048, TOKEN)
-        assert got == {"moe_scheme": kw["moe_scheme"],
-                       "microbatch": kw["microbatch"]}
-        with pytest.warns(DeprecationWarning, match="moe_dispatch_plan"):
-            d = pctx.moe_dispatch_plan(64, 8, 2048, TOKEN)
-        assert d.op == "dispatch"
-        with pytest.warns(DeprecationWarning, match="moe_combine_plan"):
-            c = pctx.moe_combine_plan(64, 8, 2048, TOKEN)
-        assert c.op == "combine"
-
-    def test_no_internal_callers_of_shims(self):
-        """The deprecation window is for EXTERNAL callers: nothing under
-        src/repro may call the shimmed APIs (backed by the pyproject
-        filterwarnings rule that escalates repro-internal shim warnings
-        to errors in tier-1)."""
-        root = os.path.join(os.path.dirname(__file__), "..", "src",
-                            "repro")
-        pat = re.compile(
-            r"\.(resolve_moe_scheme|resolve_moe_dispatch|"
-            r"resolve_combine_scheme|moe_dispatch_plan|moe_combine_plan)"
-            r"\s*\(")
-        offenders = []
-        for dirpath, _, files in os.walk(root):
-            for fname in files:
-                if not fname.endswith(".py"):
-                    continue
-                path = os.path.join(dirpath, fname)
-                if path.endswith(os.path.join("parallel", "context.py")):
-                    continue               # the shims themselves
-                with open(path) as f:
-                    for i, line in enumerate(f, 1):
-                        if pat.search(line):
-                            offenders.append(f"{path}:{i}")
-        assert not offenders, offenders
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,9 @@ Covers:
   * Hot-expert (skewed) routing scenarios: traffic concentration,
     scenario cache keying, planner pricing.
   * ParallelContext calibration wiring.
+  * The soak: a bound serving program through a 4x inter-rail
+    degradation and its recovery — detection latency, fit convergence,
+    planner-vs-oracle flips, one-shot stale warnings, SLO class walk.
 """
 
 import dataclasses
@@ -30,10 +33,11 @@ from repro.core import latency_model as lm
 from repro.core import plan as plan_ir
 from repro.core import planner as pl
 from repro.core import schedules as sch
-from repro.core.topology import full_mesh, two_server_cluster
+from repro.core.topology import full_mesh, get_fabric, two_server_cluster
 from repro.telemetry import (CalibrationStore, DriftMonitor, GroundTruth,
                              SimProbe, calibrated_hw, fit_link_classes,
-                             fit_measurements, probe_sweep, topo_key)
+                             fit_measurements, link_class, probe_sweep,
+                             reset_default_registry, topo_key)
 
 TOPO = two_server_cluster()
 
@@ -329,6 +333,7 @@ class TestClosedLoop:
         d_pre = planner.choose("dispatch", payload, TOPO,
                                token_bytes=lm.TOKEN_BYTES)
         assert d_pre.plan == "unicast"
+        flip_pre = pl.emergent_flip_batch("dispatch", TOPO, planner=planner)
 
         # rails silently degrade 4x (only measured times change)
         truth = GroundTruth(noise=0.01, seed=1).degraded(TOPO, 4.0)
@@ -349,8 +354,9 @@ class TestClosedLoop:
         assert d_post.plan == "multiwrite"
         assert planner.recalibrations >= 1
         # the emergent flip batch moved down accordingly
-        assert pl.emergent_flip_batch("dispatch", TOPO,
-                                      planner=planner) < 128
+        flip_post = pl.emergent_flip_batch("dispatch", TOPO,
+                                           planner=planner)
+        assert flip_post < 128 and flip_post < flip_pre
 
     def test_no_drift_no_recalibration(self):
         planner = pl.Planner()
@@ -609,3 +615,181 @@ class TestContextCalibration:
         d_flat = self._site_decision(pctx, 256)
         d_hot = self._site_decision(hot, 256)
         assert d_hot.predicted_s != d_flat.predicted_s
+
+
+# ---------------------------------------------------------------------------
+# soak: a 4x inter-rail degradation and its recovery, one telemetry cycle
+# per epoch, against a bound serving program
+# ---------------------------------------------------------------------------
+
+SOAK_EPOCHS = 6
+SOAK_DETECT_WITHIN = 2     # epochs from an injected event to its recal
+SOAK_SCHEDULE = ({"epoch": 1, "kind": "degrade", "factor": 4.0},
+                 {"epoch": 4, "kind": "recover"})
+SOAK_FLIP_BATCH = 64       # dispatch cell: unicast healthy, multiwrite at 4x
+SOAK_SLO_BATCH = 512       # large-payload cell whose SLO class is read
+SLO_WORST_FIRST = ("poor", "acceptable", "good")
+
+
+def _true_inter_bw(truth, topo) -> float:
+    """Mean bandwidth the truth's inter-server links actually deliver."""
+    cur = dict(truth.link_bw)
+    bws = [cur.get(key, ln.bw) for key, ln in topo.links.items()
+           if link_class(topo, *key) == "inter"]
+    return sum(bws) / len(bws)
+
+
+def _apply_event(truth, topo, event):
+    if event["kind"] == "degrade":
+        return truth.degraded(topo, event["factor"])
+    # recover: drop every per-link override and any blackout
+    return dataclasses.replace(truth, link_bw=(), dead_links=())
+
+
+def _slo_counts(reg, bucket) -> dict:
+    """Cumulative probe SLO classes of the dispatch cell at ``bucket``."""
+    counts = dict.fromkeys(SLO_WORST_FIRST, 0.0)
+    for labels, v in reg["repro_slo_class_total"].samples():
+        if (labels["op"] == "dispatch"
+                and labels["payload_bucket"] == str(bucket)
+                and labels["slo"] in counts):
+            counts[labels["slo"]] += v
+    return counts
+
+
+@pytest.fixture(scope="module")
+def soak():
+    """Serve a bound prefill/decode program through the scheduled
+    degradation: per epoch inject the event, run one DriftMonitor cycle
+    on a fresh-seeded SimProbe, run the one-shot stale check twice
+    (re-binding when stale), and read the planner's and a truth
+    oracle's dispatch choice and the epoch's worst SLO class."""
+    reg = reset_default_registry()
+    topo = get_fabric("2x8")
+    planner = pl.Planner()
+    monitor = DriftMonitor(planner, CalibrationStore(":memory:"), topo)
+    truth = GroundTruth(noise=0.01, seed=0)
+    program = plan_ir.CollectiveProgram(
+        name="soak_serve",
+        sites=(*plan_ir.moe_sites("prefill", num_experts=64, top_k=8,
+                                  tokens_per_rank=SOAK_FLIP_BATCH,
+                                  token_bytes=lm.TOKEN_BYTES),
+               *plan_ir.moe_sites("decode", num_experts=64, top_k=8,
+                                  tokens_per_rank=4,
+                                  token_bytes=lm.TOKEN_BYTES)))
+    eplan = planner.plan_program(program, topo)
+    flip_payload = float(SOAK_FLIP_BATCH) * lm.TOKEN_BYTES
+    slo_bucket = pl.bucket_payload(float(SOAK_SLO_BATCH) * lm.TOKEN_BYTES)
+    by_epoch = {ev["epoch"]: ev for ev in SOAK_SCHEDULE}
+
+    run = {"recal_epochs": [], "changed_recals": [], "stale_warnings": [],
+           "timeline": []}
+    warned = False
+    prev_slo = _slo_counts(reg, slo_bucket)
+    prev_plan = None
+    for epoch in range(SOAK_EPOCHS):
+        if epoch in by_epoch:
+            truth = _apply_event(truth, topo, by_epoch[epoch])
+        probe = SimProbe(dataclasses.replace(truth, seed=1000 + epoch))
+        recal = monitor.run_cycle(probe)
+        if recal is not None:
+            run["recal_epochs"].append(epoch)
+            if any(p["changed"] for p in recal.get("programs", [])):
+                run["changed_recals"].append(epoch)
+        stale = False
+        for _ in range(2):        # the second check must never warn again
+            stale = bool(planner.plan_is_stale(eplan))
+            if stale and not warned:
+                warned = True
+                run["stale_warnings"].append(epoch)
+        if stale:
+            eplan = (monitor.replanned(program.name)
+                     or planner.plan_program(program, topo))
+            warned = False
+        decision = planner.choose("dispatch", flip_payload, topo)
+        oracle = pl.Planner(hw=truth.true_hw()).choose(
+            "dispatch", flip_payload, topo)
+        slo = _slo_counts(reg, slo_bucket)
+        # the epoch's class is the WORST class seen in it: SLOs report
+        # the tail, so one poor probe among good ones makes it poor
+        slo_class = next((cls for cls in SLO_WORST_FIRST
+                          if slo[cls] > prev_slo[cls]), None)
+        run["timeline"].append({
+            "epoch": epoch,
+            "fits": recal["fits"] if recal else None,
+            "true_inter_bw": _true_inter_bw(truth, topo),
+            "planner_plan": decision.plan,
+            "oracle_plan": oracle.plan,
+            "flipped": prev_plan is not None and decision.plan != prev_plan,
+            "slo_class": slo_class,
+        })
+        prev_slo, prev_plan = slo, decision.plan
+    return run
+
+
+def _soak_detection(run):
+    """Every injected event trips a recalibration within the window."""
+    for ev in SOAK_SCHEDULE:
+        window = range(ev["epoch"], ev["epoch"] + SOAK_DETECT_WITHIN + 1)
+        assert any(e in window for e in run["recal_epochs"]), \
+            (ev, run["recal_epochs"])
+
+
+def _soak_convergence(run):
+    """After each class-uniform event, the last trusted inter fit in the
+    detection window lands within 20% of the injected true bandwidth."""
+    for ev in SOAK_SCHEDULE:
+        rows = [r for r in run["timeline"] if r["fits"]
+                and ev["epoch"] <= r["epoch"]
+                <= ev["epoch"] + SOAK_DETECT_WITHIN]
+        assert rows, ev
+        fit = rows[-1]["fits"].get("inter", {})
+        assert fit.get("trusted", False), (ev, fit)
+        true_bw = rows[-1]["true_inter_bw"]
+        assert abs(fit["bw_gbps"] * 1e9 - true_bw) / true_bw <= 0.20, \
+            (ev, fit, true_bw)
+
+
+def _soak_flips(run):
+    """Outside the detection grace windows the fitted planner agrees
+    with the truth oracle, and at least one genuine flip happened."""
+    grace = {e for ev in SOAK_SCHEDULE
+             for e in range(ev["epoch"],
+                            ev["epoch"] + SOAK_DETECT_WITHIN + 1)}
+    mismatches = [r["epoch"] for r in run["timeline"]
+                  if r["epoch"] not in grace
+                  and r["planner_plan"] != r["oracle_plan"]]
+    assert not mismatches, run["timeline"]
+    assert sum(r["flipped"] for r in run["timeline"]) >= 1
+
+
+def _soak_stale(run):
+    """The stale-plan warning fires exactly once per recalibration that
+    changed the bound program (a re-bind resets the one-shot)."""
+    assert run["changed_recals"]
+    assert run["stale_warnings"] == run["changed_recals"]
+
+
+def _soak_slo(run):
+    """The dispatch SLO class walks good -> poor (stale model at the
+    degradation epoch) -> good (after recalibration)."""
+    deg = next(ev["epoch"] for ev in SOAK_SCHEDULE
+               if ev["kind"] == "degrade")
+    classes = [r["slo_class"] for r in run["timeline"]]
+    pre = [c for c in classes[:deg] if c]
+    post = [c for c in classes[deg + 1:] if c]
+    assert pre and pre[-1] == "good", classes
+    assert classes[deg] == "poor", classes
+    assert "good" in post, classes
+
+
+SOAK_CHECKS = {"detection": _soak_detection,
+               "convergence": _soak_convergence,
+               "flips": _soak_flips,
+               "stale": _soak_stale,
+               "slo": _soak_slo}
+
+
+@pytest.mark.parametrize("check", sorted(SOAK_CHECKS))
+def test_soak(soak, check):
+    SOAK_CHECKS[check](soak)
